@@ -23,10 +23,11 @@ Three sections, all persisted machine-readably to ``BENCH_scale.json``:
   classical JSQ(2) and exits the band from below).
 
 CPU note: JAX exposes one host device by default, which would serialize the
-grid; this benchmark (and only it — the other benchmarks' numbers must not
-see a partitioned host) re-launches with
-``--xla_force_host_platform_device_count=<cores>`` so the grid genuinely
-spreads over cores, exactly as it would over real accelerator devices.
+grid; when ``JAX_PLATFORMS`` selects the CPU, this benchmark (and only it —
+the other benchmarks' numbers must not see a partitioned host) sets
+``--xla_force_host_platform_device_count=<cores>`` before JAX initializes,
+so the grid spreads over cores as it would over accelerator devices.  On
+an accelerator it runs on the real devices and sets nothing.
 
     PYTHONPATH=src python -m benchmarks.bench_scale [--smoke] [--json PATH]
                                                     [--single-device]
@@ -36,11 +37,13 @@ from __future__ import annotations
 import os
 import sys
 
-# Must precede the first `import jax` in this process: expose one host
-# device per core so the sweep engine's multi-device fan-out has devices
-# to fan over.  `--single-device` (or an inherited XLA_FLAGS already
-# pinning a device count, or an already-imported jax) leaves things alone.
-if ("--single-device" not in sys.argv and "jax" not in sys.modules
+# Must precede the first `import jax` in this process: on the CPU, expose
+# one host device per core so the sweep engine's multi-device fan-out has
+# devices to fan over.  `--single-device` (or an inherited XLA_FLAGS
+# already pinning a device count, or an already-imported jax, or any
+# platform but the CPU) leaves things alone.
+if (os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+        and "--single-device" not in sys.argv and "jax" not in sys.modules
         and "xla_force_host_platform_device_count"
         not in os.environ.get("XLA_FLAGS", "")):
     _ndev = min(os.cpu_count() or 1, 16)

@@ -18,10 +18,12 @@ import time
 
 
 def _run_bench_scale(smoke: bool, json_path: str):
-    """bench_scale re-launches itself so its one-host-device-per-core XLA
-    flag (a) exists before jax initializes and (b) cannot leak into the
-    other sections' single-device perf numbers.  An empty ``json_path``
-    passes through and disables the file, matching ``--json``."""
+    """bench_scale runs as a child process, launched before this process
+    imports JAX: on an accelerator the child must be the only process
+    holding it, and on the CPU its one-host-device-per-core XLA flag must
+    exist before JAX initializes and must not leak into the other
+    sections' single-device numbers.  An empty ``json_path`` passes
+    through and disables the file, matching ``--json``."""
     cmd = [sys.executable, "-m", "benchmarks.bench_scale",
            "--json", json_path] + (["--smoke"] if smoke else [])
     env = dict(os.environ)
@@ -30,6 +32,13 @@ def _run_bench_scale(smoke: bool, json_path: str):
         [os.path.join(root, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     subprocess.run(cmd, cwd=root, env=env, check=True)
+
+
+def _section(title: str, fn) -> None:
+    print(f"\n===== {title} =====", flush=True)
+    t0 = time.time()
+    fn()
+    print(f"# section time: {time.time() - t0:.1f}s", flush=True)
 
 
 def main():
@@ -55,6 +64,11 @@ def main():
     args = ap.parse_args()
     q = args.quick
 
+    t_all = time.time()
+    # First, while this process has not touched JAX (see _run_bench_scale).
+    _section("Scale studies — vmapped sweep engine (simulate_many)",
+             lambda: _run_bench_scale(smoke=q, json_path=args.json_scale))
+
     from . import (bench_azure, bench_dags, bench_faults,
                    bench_functionbench, bench_gap, bench_kernels,
                    bench_obs, bench_reliability, bench_roofline,
@@ -76,8 +90,6 @@ def main():
         ("§5 — scheduling hot-path implementations",
          # smoke=True overrides the shapes internally (T=128, m=120)
          lambda: bench_kernels.main(smoke=q, json_path=args.json or None)),
-        ("Scale studies — vmapped sweep engine (simulate_many)",
-         lambda: _run_bench_scale(smoke=q, json_path=args.json_scale)),
         ("Scenario engine — bursty/diurnal/outage/churn grid",
          lambda: bench_scenarios.main(smoke=q,
                                       json_path=args.json_scenarios
@@ -105,12 +117,8 @@ def main():
         ("§Roofline — fused-kernel bytes-touched model vs measurement",
          lambda: bench_roofline.main(smoke=q)),
     ]
-    t_all = time.time()
     for title, fn in sections:
-        print(f"\n===== {title} =====", flush=True)
-        t0 = time.time()
-        fn()
-        print(f"# section time: {time.time() - t0:.1f}s", flush=True)
+        _section(title, fn)
     print(f"\n# total benchmark time: {time.time() - t_all:.1f}s")
 
 

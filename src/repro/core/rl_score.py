@@ -13,9 +13,15 @@ All functions are pure jnp and vmap/scan friendly. ``rl_score_matrix`` is the
 batched form (tasks × servers) that the Pallas kernel
 (`repro.kernels.rl_score`) implements for the MXU; `ref.py` of that kernel
 delegates here so the kernel is tested against this exact definition.
+
+The per-decision scores reduce ``r · L`` as an elementwise product and a sum,
+never as a dot: on a TPU an f32 dot at default precision rounds its operands
+to bf16, and the sequential, batched, served and kernel paths must compute
+the same f32 score on every backend.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 _EPS = 1e-9  # guards 0/0 when both candidates are fully idle
@@ -26,7 +32,7 @@ def rl(r: jnp.ndarray, L: jnp.ndarray, C: jnp.ndarray) -> jnp.ndarray:
 
     r: [K] task demand; L: [K] server load; C: [K] server capacity.
     """
-    return jnp.dot(r, L) / jnp.sum(C * C)
+    return jnp.sum(r * L) / jnp.sum(C * C)
 
 
 def rl_score_matrix(r: jnp.ndarray, L: jnp.ndarray, C: jnp.ndarray) -> jnp.ndarray:
@@ -35,7 +41,8 @@ def rl_score_matrix(r: jnp.ndarray, L: jnp.ndarray, C: jnp.ndarray) -> jnp.ndarr
     score[t, j] = (r_t · L_j) / ||C_j||²  — a matmul with per-column scaling.
     """
     inv_cap = 1.0 / jnp.sum(C * C, axis=-1)          # [N]
-    return (r @ L.T) * inv_cap[None, :]              # [T, N]
+    rl_tn = jnp.matmul(r, L.T, precision=jax.lax.Precision.HIGHEST)
+    return rl_tn * inv_cap[None, :]                  # [T, N]
 
 
 def load_score_pair(
@@ -79,7 +86,8 @@ def load_score_batched(
 
     Returns scores [T, 2].
     """
-    rl_ab = jnp.einsum("tk,tck->tc", r, L_ab) / jnp.sum(C_ab * C_ab, axis=-1)
+    rl_ab = (jnp.sum(r[:, None, :] * L_ab, axis=-1)
+             / jnp.sum(C_ab * C_ab, axis=-1))
     rl_sum = jnp.sum(rl_ab, axis=-1, keepdims=True)
     d_sum = jnp.sum(D_ab, axis=-1, keepdims=True)
     rl_frac = jnp.where(rl_sum > _EPS, rl_ab / (rl_sum + _EPS), 0.5)

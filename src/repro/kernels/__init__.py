@@ -3,7 +3,12 @@
 Each subpackage ships three layers:
 
 * ``kernel.py`` — the ``pl.pallas_call`` body with explicit BlockSpec VMEM
-  tiling (TPU is the *target*; this container validates via interpret mode);
+  tiling.  On a TPU it compiles through Mosaic; elsewhere (the CPU test
+  path, ``JAX_PLATFORMS=cpu``) it runs in Pallas interpret mode.  Of the
+  scheduler's kernels only the sparse ``dodoor_fused_sparse`` megakernel is
+  on the engine's path and compiled for the chip (``tests/
+  test_chip_compile.py``); the dense variants and ``rl_score`` have only
+  run interpreted;
 * ``ops.py``    — the jit'd public wrapper (padding, grid math, dtypes);
 * ``ref.py``    — the pure-jnp oracle every kernel is tested against.
 

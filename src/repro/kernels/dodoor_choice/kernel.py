@@ -22,54 +22,71 @@ Two entry points share that trick:
   ``use_kernel=True`` stays legal under outage/churn timelines.  Sampling
   arithmetic is otherwise identical, so draws remain bit-exact against
   ``sample_feasible_batch`` on the intersected mask.
-* ``dodoor_fused_sparse_pallas`` (+ ``_sparse_masked``) — the
-  sparse-candidate-gather megakernel.  The dense form streams a
-  ``d [T, N]`` per-server duration plane per tile — the operand that
-  breaks the 10⁴-server ceiling (it is the only [T, N] input, and the
-  engine materializes it from a tiny ``[T, num_types]`` table).  The
-  sparse form streams that ``d_types [T, TT]`` table instead (TT = node
-  types, ~4) and carries each server's node type as one extra table
-  column; after the candidate rows are gathered, the candidate's duration
-  is a second (tiny) one-hot pick over the TT type columns.  Per-task
-  bytes touched drop from O(N) to O(TT + N/block_t·(2K+3)) — the
-  full-row read is gone.  Sampling arithmetic is untouched, so draws stay
-  bit-exact against ``sample_feasible_batch``, and the gathered duration
-  is the *same float* the dense kernel gathers (``d[t, j] ==
-  d_types[t, node_type[j]]`` by construction), so choices/scores match
-  the dense megakernel exactly.
+* ``dodoor_fused_sparse_pallas`` — the sparse-candidate-gather
+  megakernel, with optional availability and locality planes.  It is the
+  only kernel the engine runs, and the only one made for the chip's
+  compiler (the ones above have only run interpreted).  The dense form
+  streams a ``d [T, N]`` per-server duration plane per tile; the sparse
+  form streams the engine's ``d_types [T, TT]`` table instead (TT = node
+  types, ~4) and carries each server's node type as one more table
+  field, so the candidate's duration is a TT-wide pick.  The gathered
+  duration is the *same float* the dense kernel gathers (``d[t, j] ==
+  d_types[t, node_type[j]]`` by construction).
 
-Megakernel VMEM layout
-----------------------
-The per-tile VMEM working set is one packed server table plus the tile's
-task rows:
+Sparse megakernel layout (what Mosaic compiles)
+-----------------------------------------------
+Servers run along the 128-wide lane axis.  The server table is stored
+transposed, one row per field, padded to the 8-row sublane tile and to
+``NP`` = n rounded up to 128 lanes:
 
-    tbl[N, 2K+2] = [ L (K cols) | D | 1/ΣC² | C (K cols) ]
+    tbl[8, NP] = [ L (K rows) | D | 1/ΣC² | C (K rows) | node_type | 0 ]
 
-Columns 0..K-1 feed the RL numerator (one-hot matmul), column K the
-duration term, column K+1 the precomputed reciprocal capacity norm
-(Eq. 1's denominator), and the trailing K *prefilter columns* the
-feasibility mask (Algorithm 1 line 2: ``r ≤ C`` in every dimension).
-An 8192-node fleet at K=2 is ~192 KB — well under the ~16 MB/core VMEM
-budget — and the table block is pinned to grid index 0, so every tile
-reads it from HBM once.  Streamed per tile: ``key[block_t, 2]`` (uint32),
-``r[block_t, K]``, ``d[block_t, N]`` (per-server estimated durations).
+At K = 2 and n = 10⁴ that is 8 × 10,112 × 4 B ≈ 316 KiB (a row-major
+``[n, 7]`` table would pad its 7 columns to 128 lanes, ≈ 4.9 MiB).  Its
+block is pinned to grid index 0, so it is read from HBM once.  Per-task
+values are ``[block_t, 1]`` columns and per-server values ``[1, NP]``
+rows, so every intermediate plane is a lane-dense ``[block_t, NP]`` tile:
+the feasibility mask, the lane iota, each binary-search probe and each
+gather's hit mask.  ``ops._clamp_block`` caps ``block_t`` so one 32-bit
+plane stays within ``PLANE_BYTES`` (1 MiB): 24 rows at n = 10⁴, about
+0.93 MiB a plane.  A few such planes live at once, plus the
+double-buffered ``avail`` plane of the masked form; ``VMEM_LIMIT_BYTES``
+(32 MiB) raises the scoped-VMEM limit above the 16 MiB default to give
+them room.  All outputs are 2-D (``choice [T, 1]``), so no rank-1 block
+has to be a multiple of 128.
+
+Two steps are built from operations Mosaic lowers:
+
+* The inverse-CDF pick of ``sample_feasible`` takes the first server whose
+  inclusive feasible count reaches the rank.  The count is nondecreasing,
+  so a binary search over the server index finds it; each probe is a
+  masked lane sum, and ``ceil(log2 n)`` probes replace the prefix sum
+  (``cumsum`` has no Mosaic lowering).  The result is the same index.
+* Each candidate field is a masked lane sum with one nonzero term, so the
+  gathered L, D, 1/ΣC² and node type are the stored f32 values exactly.
+  No matmul is involved; an f32 matmul at default precision on the TPU's
+  MXU rounds its operands to bf16.
 
 Megakernel PRNG scheme
 ----------------------
 Candidate draws must be *draw-for-draw identical* to the two-stage path's
 ``jax.random.uniform(k_cand, (2,))``, so the kernel re-implements JAX's
 threefry2x32 generator inline (20 rounds, rotation schedule
-(13,15,26,6)/(17,29,16,24), key-schedule constant 0x1BD11BDA):
+(13,15,26,6)/(17,29,16,24), key-schedule constant 0x1BD11BDA).  The
+installed JAX (0.9) draws in the partitionable layout (its
+``jax_threefry_partitionable`` default is on), where element i of the
+draw hashes counter ``(0, i)`` and folds the two output words:
 
-    bits0, bits1 = threefry2x32(key_lo, key_hi, counts=(0, 1))
-    u            = bitcast(bits >> 9 | 0x3F800000, f32) - 1.0
+    hi_i, lo_i = threefry2x32(key, counts=(0, i))       i = 0, 1
+    u_i        = bitcast((hi_i ^ lo_i) >> 9 | 0x3F800000, f32) - 1.0
 
-exactly the mantissa-fill JAX uses for float32 uniforms.  The two uniforms
-then drive the same inverse-CDF pick as ``sample_feasible``: inclusive
-prefix-sum of the feasibility mask, rank ``min(int(u·k), k-1)+1``, index =
-#servers whose prefix count is below the rank (with the uniform-over-all
-fallback when no server is feasible).  ``tests/test_kernels.py`` /
-``tests/test_engine_batched.py`` pin this bit-for-bit against
+exactly the mantissa fill JAX uses for float32 uniforms.  The program
+follows the installation's default and sets no flag.  The two uniforms
+then drive the same inverse-CDF pick as ``sample_feasible``: rank
+``min(int(u·k), k-1)+1``, index = #servers whose inclusive feasible count
+is below the rank (with the uniform-over-all fallback when no server is
+feasible).  ``tests/test_kernels.py`` / ``tests/test_engine_batched.py``
+pin this bit-for-bit against ``jax.random.uniform`` and
 ``sample_feasible_batch``.
 
 Grid: 1-D over decision-batch tiles of ``block_t``. The server table is
@@ -85,8 +102,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-9
+
+#: Servers ride the 128-wide lane axis of the sparse kernel's planes.
+LANES = 128
+#: Bytes one ``[block_t, NP]`` 32-bit plane of the sparse kernel may take;
+#: ``ops._clamp_block`` sizes ``block_t`` from it.
+PLANE_BYTES = 1 << 20
+#: Scoped-VMEM limit of the sparse kernel: its tile holds a handful of
+#: such planes (mask, iota, probe temporaries, the double-buffered avail
+#: plane) plus the pinned table.
+VMEM_LIMIT_BYTES = 32 << 20
 
 # threefry2x32 rotation schedule (Salmon et al.; matches jax._src.prng).
 _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
@@ -121,15 +149,26 @@ def _unit_float(bits):
     return jax.lax.bitcast_convert_type(fb, jnp.float32) - 1.0
 
 
-def _pair_scores(alpha, k, r, row_a, row_b, d_a, d_b):
-    """LOADSCORE for gathered candidate rows (shared by both kernels).
+def _uniform_pair(k0, k1):
+    """``jax.random.uniform(key, (2,))`` for key words ``(k0, k1)``, in the
+    partitionable threefry layout: element i is ``hi ^ lo`` of
+    ``threefry2x32(key, (0, i))``."""
+    zero = jnp.zeros_like(k0)
+    a0, b0 = _threefry2x32(k0, k1, zero, zero)
+    a1, b1 = _threefry2x32(k0, k1, zero, zero + jnp.uint32(1))
+    return _unit_float(a0 ^ b0), _unit_float(a1 ^ b1)
 
-    ``row_*[:, :k]`` = L, ``[:, k]`` = D, ``[:, k+1]`` = 1/ΣC².
-    """
-    rl_a = jnp.sum(r * row_a[:, :k], axis=-1) * row_a[:, k + 1]
-    rl_b = jnp.sum(r * row_b[:, :k], axis=-1) * row_b[:, k + 1]
-    D_a = row_a[:, k] + d_a
-    D_b = row_b[:, k] + d_b
+
+def _row_terms(k, r, row, d_c):
+    """Eq. 1's RL and the duration term for gathered candidate rows
+    ``row[:, :k]`` = L, ``[:, k]`` = D, ``[:, k+1]`` = 1/ΣC²."""
+    rl = jnp.sum(r * row[:, :k], axis=-1) * row[:, k + 1]
+    return rl, row[:, k] + d_c
+
+
+def _pair_scores(alpha, rl_a, D_a, rl_b, D_b):
+    """LOADSCORE from both candidates' RL and duration terms (shared by
+    every kernel)."""
     rl_sum = rl_a + rl_b
     d_sum = D_a + D_b
     rl_fa = jnp.where(rl_sum > _EPS, rl_a / (rl_sum + _EPS), 0.5)
@@ -156,13 +195,15 @@ def _kernel(alpha, r_ref, cand_ref, d_ref, tbl_ref, out_choice_ref,
 
     def gather(which):
         onehot = (cand[:, which][:, None] == ids).astype(jnp.float32)
-        return jnp.dot(onehot, tbl, preferred_element_type=jnp.float32)
+        return jnp.dot(onehot, tbl, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
 
     row_a = gather(0)                                      # [bt, K+2]
     row_b = gather(1)
     r = r_ref[...]
-    score_a, score_b = _pair_scores(alpha, k, r, row_a, row_b,
-                                    d_ref[:, 0], d_ref[:, 1])
+    score_a, score_b = _pair_scores(
+        alpha, *_row_terms(k, r, row_a, d_ref[:, 0]),
+        *_row_terms(k, r, row_b, d_ref[:, 1]))
 
     out_scores_ref[:, 0] = score_a
     out_scores_ref[:, 1] = score_b
@@ -239,11 +280,7 @@ def _fused_kernel(alpha, k, masked, *refs):
     kk = jnp.where(any_ok, total, n)                       # [bt]
 
     # --- per-task PRNG: uniform(k_cand, (2,)) via inline threefry
-    y0, y1 = _threefry2x32(key_ref[:, 0], key_ref[:, 1],
-                           jnp.zeros((bt,), jnp.uint32),
-                           jnp.ones((bt,), jnp.uint32))
-    u0 = _unit_float(y0)
-    u1 = _unit_float(y1)
+    u0, u1 = _uniform_pair(key_ref[:, 0], key_ref[:, 1])
 
     # --- inverse-CDF prefix-sum pick (two independent RandomInt draws)
     kk_f = kk.astype(jnp.float32)
@@ -259,13 +296,15 @@ def _fused_kernel(alpha, k, masked, *refs):
 
     def gather(c):
         onehot = (c[:, None] == ids).astype(jnp.float32)
-        row = jnp.dot(onehot, tbl, preferred_element_type=jnp.float32)
+        row = jnp.dot(onehot, tbl, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
         d_c = jnp.sum(onehot * d, axis=-1)
         return row, d_c
 
     row_a, d_a = gather(cand0)
     row_b, d_b = gather(cand1)
-    score_a, score_b = _pair_scores(alpha, k, r, row_a, row_b, d_a, d_b)
+    score_a, score_b = _pair_scores(alpha, *_row_terms(k, r, row_a, d_a),
+                                    *_row_terms(k, r, row_b, d_b))
 
     out_cand_ref[:, 0] = cand0.astype(jnp.int32)
     out_cand_ref[:, 1] = cand1.astype(jnp.int32)
@@ -347,84 +386,103 @@ def dodoor_fused_masked_pallas(keys, r, d, avail, tbl, *, alpha: float,
     )(keys, r, d, avail, tbl)
 
 
-def _fused_sparse_kernel(alpha, k, masked, gamma_bw, locality, *refs):
-    # key_ref:  [block_t, 2]   per-task uint32 PRNG key (k_cand)
-    # r_ref:    [block_t, K]   task demands
-    # dt_ref:   [block_t, TT]  per-*type* estimated durations (TT = node
-    #                          types) — replaces the dense [block_t, N]
-    #                          per-server plane
-    # avail_ref:[block_t, N]   (masked form only) 0/1 availability plane
-    # psrv_ref: [block_t, P]   (locality form only) parent servers (i32,
-    #                          -1 where absent)
-    # pbytes_ref:[block_t, P]  (locality form only) parent output MB (0
-    #                          where absent — an absent parent is inert)
-    # tbl_ref:  [N, 2K+3]      server table: [L | D | 1/ΣC² | C | node_type]
-    # outputs:  choice [bt] i32, cand [bt, 2] i32, scores [bt, 2] f32
+def _fused_sparse_kernel(alpha, k, n, masked, gamma_bw, locality, *refs):
+    # Every per-task value is a [bt, 1] column and every per-server value a
+    # [1, NP] row (NP = servers padded to 128 lanes), so each plane is a
+    # lane-dense [bt, NP] tile that Mosaic lowers without relayouts.
+    # key_ref:  [bt, 2]   per-task uint32 PRNG key (k_cand)
+    # r_ref:    [bt, K]   task demands
+    # dt_ref:   [bt, TT]  per-*type* estimated durations (TT = node types)
+    # avail_ref:[bt, NP]  (masked form only) 0/1 availability plane
+    # psrv_ref: [bt, P]   (locality form only) parent servers (i32, -1
+    #                     where absent)
+    # pbytes_ref:[bt, P]  (locality form only) parent output MB (0 where
+    #                     absent — an absent parent is inert)
+    # tbl_ref:  [R, NP]   transposed server table, one row per field:
+    #                     [L (K) | D | 1/ΣC² | C (K) | node_type | 0 pad]
+    # outputs:  choice [bt, 1] i32, cand [bt, 2] i32, scores [bt, 2] f32
     refs = list(refs)
     key_ref, r_ref, dt_ref = refs[:3]
-    pos = 3
+    at = 3
     avail_ref = psrv_ref = pbytes_ref = None
     if masked:
-        avail_ref = refs[pos]
-        pos += 1
+        avail_ref = refs[at]
+        at += 1
     if locality:
-        psrv_ref, pbytes_ref = refs[pos], refs[pos + 1]
-        pos += 2
-    tbl_ref, out_choice_ref, out_cand_ref, out_scores_ref = refs[pos:]
-    tbl = tbl_ref[...]
-    n = tbl.shape[0]
+        psrv_ref, pbytes_ref = refs[at], refs[at + 1]
+        at += 2
+    tbl_ref, out_choice_ref, out_cand_ref, out_scores_ref = refs[at:]
+    bt = r_ref.shape[0]
+    n_pad = tbl_ref.shape[1]
     r = r_ref[...]
-    bt = r.shape[0]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bt, n_pad), 1)
 
-    # --- prefilter + draws: identical arithmetic to _fused_kernel (the
-    #     draw-for-draw contract with sample_feasible) — only the duration
-    #     gather below differs.
-    caps = tbl[:, k + 2:2 * k + 2]                         # [N, K]
-    mask = jnp.all(r[:, None, :] <= caps[None, :, :], axis=-1)   # [bt, N]
+    # --- prefilter (Algorithm 1 line 2): r ≤ C in every dimension, on the
+    #     real servers only, intersected with the availability plane.
+    mask = pos < n
+    for c in range(k):
+        mask = mask & (r[:, c:c + 1] <= tbl_ref[k + 2 + c:k + 3 + c, :])
     if avail_ref is not None:
         mask = mask & (avail_ref[...] > 0.0)
-    cnt = jnp.cumsum(mask.astype(jnp.int32), axis=1)       # inclusive
-    total = cnt[:, -1]                                     # [bt]
+    ones = mask.astype(jnp.int32)
+    total = jnp.sum(ones, axis=1, keepdims=True)           # [bt, 1]
     any_ok = total > 0
-    pos = jax.lax.broadcasted_iota(jnp.int32, (bt, n), 1)
-    eff_cnt = jnp.where(any_ok[:, None], cnt, pos + 1)
-    kk = jnp.where(any_ok, total, n)                       # [bt]
+    kk = jnp.where(any_ok, total, n)
 
-    y0, y1 = _threefry2x32(key_ref[:, 0], key_ref[:, 1],
-                           jnp.zeros((bt,), jnp.uint32),
-                           jnp.ones((bt,), jnp.uint32))
-    u0 = _unit_float(y0)
-    u1 = _unit_float(y1)
-
+    # --- the two RandomInt draws, exactly sample_feasible's arithmetic:
+    #     rank = min(int(u·kk), kk-1) + 1, index = #servers whose inclusive
+    #     feasible count is below the rank.  The count is nondecreasing, so
+    #     that index is the first j with count[j] ≥ rank: a binary search
+    #     whose probes are masked lane sums (no prefix-sum primitive, which
+    #     Mosaic does not lower).  With nothing feasible, sample_feasible
+    #     counts every server, and the index is rank - 1.
+    keys = key_ref[...]
+    u0, u1 = _uniform_pair(keys[:, 0:1], keys[:, 1:2])
     kk_f = kk.astype(jnp.float32)
-    km1 = kk - 1
-    tgt0 = jnp.minimum((u0 * kk_f).astype(jnp.int32), km1) + 1
-    tgt1 = jnp.minimum((u1 * kk_f).astype(jnp.int32), km1) + 1
-    cand0 = jnp.sum((eff_cnt < tgt0[:, None]).astype(jnp.int32), axis=1)
-    cand1 = jnp.sum((eff_cnt < tgt1[:, None]).astype(jnp.int32), axis=1)
+    tgt0 = jnp.minimum((u0 * kk_f).astype(jnp.int32), kk - 1) + 1
+    tgt1 = jnp.minimum((u1 * kk_f).astype(jnp.int32), kk - 1) + 1
+    lo0 = lo1 = jnp.zeros((bt, 1), jnp.int32)
+    hi0 = hi1 = jnp.full((bt, 1), n - 1, jnp.int32)
+    for _ in range(max(n - 1, 0).bit_length()):
+        mid0 = (lo0 + hi0) >> 1
+        mid1 = (lo1 + hi1) >> 1
+        ok0 = jnp.sum(jnp.where(pos <= mid0, ones, 0), axis=1,
+                      keepdims=True) >= tgt0
+        ok1 = jnp.sum(jnp.where(pos <= mid1, ones, 0), axis=1,
+                      keepdims=True) >= tgt1
+        lo0, hi0 = jnp.where(ok0, lo0, mid0 + 1), jnp.where(ok0, mid0, hi0)
+        lo1, hi1 = jnp.where(ok1, lo1, mid1 + 1), jnp.where(ok1, mid1, hi1)
+    cand0 = jnp.where(any_ok, lo0, tgt0 - 1)
+    cand1 = jnp.where(any_ok, lo1, tgt1 - 1)
 
-    # --- sparse gather: candidate rows from the table (one-hot matmul as
-    #     before), then the candidate's *node type* rides out as the last
-    #     table column — node types are small ints, exactly representable
-    #     in f32 and exactly recovered by the single-nonzero one-hot sum —
-    #     and a second, tiny one-hot over the TT type columns picks the
-    #     duration.  No [bt, N] duration operand exists anywhere.
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    # --- sparse gather: each table field of the candidate is a masked lane
+    #     sum with one nonzero term, so it is the stored f32 exactly (no
+    #     matmul, whose f32 precision on the MXU is not exact).  The
+    #     candidate's node type rides out as a table field, and a TT-wide
+    #     pick resolves its duration; no [bt, N] duration operand exists.
     dt = dt_ref[...]                                       # [bt, TT]
-    tt_n = dt.shape[1]
-    tio = jax.lax.broadcasted_iota(jnp.float32, (1, tt_n), 1)
+    tio = jax.lax.broadcasted_iota(jnp.int32, (1, dt.shape[1]),
+                                   1).astype(jnp.float32)
 
-    def gather(c):
-        onehot = (c[:, None] == ids).astype(jnp.float32)
-        row = jnp.dot(onehot, tbl, preferred_element_type=jnp.float32)
-        nt_c = row[:, 2 * k + 2]                           # [bt] exact
-        d_c = jnp.sum((nt_c[:, None] == tio).astype(jnp.float32) * dt,
-                      axis=-1)
-        return row, d_c
+    def gather(cand):
+        hit = pos == cand
 
-    row_a, d_a = gather(cand0)
-    row_b, d_b = gather(cand1)
-    score_a, score_b = _pair_scores(alpha, k, r, row_a, row_b, d_a, d_b)
+        def field(i):
+            return jnp.sum(jnp.where(hit, tbl_ref[i:i + 1, :], 0.0), axis=1,
+                           keepdims=True)
+
+        # L as a [bt, K] tile, so that RL reduces over K exactly as the
+        # jnp paths do (a reduction, not a chain of adds).
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bt, k), 1)
+        l_c = field(0)
+        for c in range(1, k):
+            l_c = jnp.where(lane == c, field(c), l_c)
+        rl = jnp.sum(r * l_c, axis=1, keepdims=True) * field(k + 1)
+        d_c = jnp.sum(jnp.where(field(2 * k + 2) == tio, dt, 0.0), axis=1,
+                      keepdims=True)
+        return rl, field(k) + d_c
+
+    score_a, score_b = _pair_scores(alpha, *gather(cand0), *gather(cand1))
 
     if locality:
         # Data-locality penalty (Algorithm 1 + LocalityModel): each
@@ -434,124 +492,70 @@ def _fused_sparse_kernel(alpha, k, masked, gamma_bw, locality, *refs):
         # locality-free scores bit-exactly.
         psrv = psrv_ref[...]                               # [bt, P] i32
         pb = pbytes_ref[...]                               # [bt, P] f32
-        rem_a = jnp.sum(
-            pb * (psrv != cand0[:, None]).astype(jnp.float32), axis=-1)
-        rem_b = jnp.sum(
-            pb * (psrv != cand1[:, None]).astype(jnp.float32), axis=-1)
+        rem_a = jnp.sum(jnp.where(psrv != cand0, pb, 0.0), axis=1,
+                        keepdims=True)
+        rem_b = jnp.sum(jnp.where(psrv != cand1, pb, 0.0), axis=1,
+                        keepdims=True)
         score_a = score_a + gamma_bw * rem_a
         score_b = score_b + gamma_bw * rem_b
 
-    out_cand_ref[:, 0] = cand0.astype(jnp.int32)
-    out_cand_ref[:, 1] = cand1.astype(jnp.int32)
-    out_scores_ref[:, 0] = score_a
-    out_scores_ref[:, 1] = score_b
-    out_choice_ref[...] = jnp.where(score_a > score_b, cand1,
-                                    cand0).astype(jnp.int32)
+    first = jax.lax.broadcasted_iota(jnp.int32, (bt, 2), 1) == 0
+    out_cand_ref[...] = jnp.where(first, cand0, cand1)
+    out_scores_ref[...] = jnp.where(first, score_a, score_b)
+    out_choice_ref[...] = jnp.where(score_a > score_b, cand1, cand0)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("alpha", "gamma_bw", "block_t",
+                   static_argnames=("n", "alpha", "gamma_bw", "block_t",
                                     "interpret"))
-def dodoor_fused_sparse_pallas(keys, r, d_types, tbl, psrv=None,
-                               pbytes=None, *, alpha: float,
+def dodoor_fused_sparse_pallas(keys, r, d_types, tbl, avail=None, psrv=None,
+                               pbytes=None, *, n: int, alpha: float,
                                gamma_bw: float = 0.0, block_t: int = 256,
                                interpret: bool | None = None):
-    """keys [T,2] uint32, r [T,K], d_types [T,TT], tbl [N, 2K+3] →
-    (choice [T], cand [T,2], scores [T,2]).  T must be a multiple of
-    block_t (ops.py pads).
+    """keys [T,2] uint32, r [T,K], d_types [T,TT], tbl [R, NP] (the
+    transposed server table, NP a multiple of 128 ≥ n) → (choice [T,1],
+    cand [T,2], scores [T,2]).  T must be a multiple of block_t (ops.py
+    pads).
 
-    ``psrv [T, P]`` (int32 parent servers, −1 padded) and ``pbytes
-    [T, P]`` (parent output MB, 0 padded) stream the locality gather:
-    each candidate's score is charged ``gamma_bw`` per MB of parent
-    output held on a different server.  ``None`` (the default) keeps the
-    locality-free program; ``gamma_bw = 0`` with planes present is
-    bit-identical to it."""
+    ``avail [T, NP]`` (optional) is the 0/1 availability plane ANDed into
+    the prefilter.  ``psrv [T, P]`` (int32 parent servers, −1 padded) and
+    ``pbytes [T, P]`` (parent output MB, 0 padded) stream the locality
+    gather: each candidate's score is charged ``gamma_bw`` per MB of parent
+    output held on a different server.  ``gamma_bw = 0`` with planes
+    present is bit-identical to running without them."""
     T, K = r.shape
-    N = tbl.shape[0]
+    R, NP = tbl.shape
     TT = d_types.shape[1]
-    grid = (T // block_t,)
+    masked = avail is not None
     locality = psrv is not None
-    kern = functools.partial(_fused_sparse_kernel, alpha, K, False,
+    kern = functools.partial(_fused_sparse_kernel, alpha, K, n, masked,
                              gamma_bw, locality)
-    in_specs = [
-        pl.BlockSpec((block_t, 2), lambda i: (i, 0)),
-        pl.BlockSpec((block_t, K), lambda i: (i, 0)),
-        pl.BlockSpec((block_t, TT), lambda i: (i, 0)),
-    ]
+
+    def rows(width):
+        return pl.BlockSpec((block_t, width), lambda i: (i, 0))
+
+    in_specs = [rows(2), rows(K), rows(TT)]
     operands = [keys, r, d_types]
+    if masked:
+        in_specs.append(rows(NP))
+        operands.append(avail)
     if locality:
-        P = psrv.shape[1]
-        in_specs += [pl.BlockSpec((block_t, P), lambda i: (i, 0)),
-                     pl.BlockSpec((block_t, P), lambda i: (i, 0))]
+        in_specs += [rows(psrv.shape[1]), rows(psrv.shape[1])]
         operands += [psrv, pbytes]
-    in_specs.append(pl.BlockSpec((N, 2 * K + 3), lambda i: (0, 0)))
+    in_specs.append(pl.BlockSpec((R, NP), lambda i: (0, 0)))
     operands.append(tbl)
     return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(T // block_t,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_t,), lambda i: (i,)),
-            pl.BlockSpec((block_t, 2), lambda i: (i, 0)),
-            pl.BlockSpec((block_t, 2), lambda i: (i, 0)),
-        ],
+        out_specs=[rows(1), rows(2), rows(2)],
         out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.int32),
+            jax.ShapeDtypeStruct((T, 1), jnp.int32),
             jax.ShapeDtypeStruct((T, 2), jnp.int32),
             jax.ShapeDtypeStruct((T, 2), jnp.float32),
         ],
-        interpret=_resolve_interpret(interpret),
-    )(*operands)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("alpha", "gamma_bw", "block_t",
-                                    "interpret"))
-def dodoor_fused_sparse_masked_pallas(keys, r, d_types, avail, tbl,
-                                      psrv=None, pbytes=None, *,
-                                      alpha: float, gamma_bw: float = 0.0,
-                                      block_t: int = 256,
-                                      interpret: bool | None = None):
-    """Masked-sampling form of :func:`dodoor_fused_sparse_pallas`: the
-    ``avail [T, N]`` 0/1 plane is ANDed into the in-kernel prefilter
-    exactly as in :func:`dodoor_fused_masked_pallas` — draws stay
-    bit-identical to ``sample_feasible_batch`` on the intersected mask.
-    Locality planes (``psrv``/``pbytes``/``gamma_bw``) compose as in the
-    unmasked form."""
-    T, K = r.shape
-    N = tbl.shape[0]
-    TT = d_types.shape[1]
-    grid = (T // block_t,)
-    locality = psrv is not None
-    kern = functools.partial(_fused_sparse_kernel, alpha, K, True,
-                             gamma_bw, locality)
-    in_specs = [
-        pl.BlockSpec((block_t, 2), lambda i: (i, 0)),
-        pl.BlockSpec((block_t, K), lambda i: (i, 0)),
-        pl.BlockSpec((block_t, TT), lambda i: (i, 0)),
-        pl.BlockSpec((block_t, N), lambda i: (i, 0)),
-    ]
-    operands = [keys, r, d_types, avail]
-    if locality:
-        P = psrv.shape[1]
-        in_specs += [pl.BlockSpec((block_t, P), lambda i: (i, 0)),
-                     pl.BlockSpec((block_t, P), lambda i: (i, 0))]
-        operands += [psrv, pbytes]
-    in_specs.append(pl.BlockSpec((N, 2 * K + 3), lambda i: (0, 0)))
-    operands.append(tbl)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_t,), lambda i: (i,)),
-            pl.BlockSpec((block_t, 2), lambda i: (i, 0)),
-            pl.BlockSpec((block_t, 2), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((T, 2), jnp.int32),
-            jax.ShapeDtypeStruct((T, 2), jnp.float32),
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_resolve_interpret(interpret),
     )(*operands)

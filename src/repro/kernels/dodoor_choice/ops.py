@@ -4,16 +4,26 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .kernel import (dodoor_choice_pallas, dodoor_fused_masked_pallas,
-                     dodoor_fused_pallas, dodoor_fused_sparse_masked_pallas,
+from .kernel import (LANES, PLANE_BYTES, dodoor_choice_pallas,
+                     dodoor_fused_masked_pallas, dodoor_fused_pallas,
                      dodoor_fused_sparse_pallas)
 
 
-def _clamp_block(T: int, block_t: int) -> int:
-    """Smallest multiple of 8 covering the batch, capped at ``block_t`` so
-    small decision blocks (the engine's partial tail, or b ≪ 256) do not pay
-    for a full tile of padding in interpret mode."""
-    return max(8, min(block_t, -(-T // 8) * 8))
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _clamp_block(T: int, block_t: int, n_pad: int | None = None) -> int:
+    """The tile of task rows: a multiple of 8 (the sublane tile) covering
+    the batch, capped at ``block_t`` so small decision blocks (the engine's
+    partial tail, or b ≪ 256) do not pay for a full tile of padding.  With
+    ``n_pad`` (the sparse kernel's lane-padded server count) it is also
+    capped so one ``[tile, n_pad]`` 32-bit plane fits ``PLANE_BYTES`` —
+    at n = 10⁴ that is 24 rows."""
+    cap = block_t
+    if n_pad is not None:
+        cap = min(cap, PLANE_BYTES // (4 * n_pad) // 8 * 8)
+    return max(8, min(cap, _round_up(T, 8)))
 
 
 def _key_data(keys: jnp.ndarray) -> jnp.ndarray:
@@ -117,14 +127,17 @@ def dodoor_fused_sparse(keys: jnp.ndarray, r: jnp.ndarray,
     (TT = number of node types, ~4) and node_type [N] maps servers to
     types — the factorization the engine's duration model already has
     (``d[t, j] == d_types[t, node_type[j]]``).  The kernel carries
-    node_type as one extra server-table column and resolves each sampled
-    candidate's duration with a tiny one-hot pick over the TT columns, so
-    the per-task bytes touched drop from O(N) to O(TT).
+    node_type as one extra server-table field and resolves each sampled
+    candidate's duration with a tiny pick over the TT columns, so the
+    per-task bytes touched drop from O(N) to O(TT).
 
     Candidate draws are bit-exact vs ``sample_feasible_batch`` (same
-    in-kernel threefry + inverse-CDF as :func:`dodoor_fused`), and
+    threefry uniforms and inverse-CDF rank as :func:`dodoor_fused`), and
     choices/scores are exactly the dense megakernel's on the factorized
     ``d`` — the gathered duration is the same float.
+
+    avail [T, N] (optional): per-task server availability, ANDed into the
+    prefilter (the masked-sampling form).
 
     psrv [T, P] / pbytes [T, P] (optional, together): the locality
     gather — each task's parent servers (int32, −1 padded) and their
@@ -136,45 +149,39 @@ def dodoor_fused_sparse(keys: jnp.ndarray, r: jnp.ndarray,
     Returns (choice [T] int32, cand [T, 2] int32, scores [T, 2] f32).
     """
     T, K = r.shape
-    block_t = _clamp_block(T, block_t)
-    Cf = C.astype(jnp.float32)
-    inv = 1.0 / jnp.sum(Cf ** 2, axis=-1, keepdims=True)
-    nt = node_type.astype(jnp.float32)[:, None]
-    tbl = jnp.concatenate([L.astype(jnp.float32),
-                           D.astype(jnp.float32)[:, None], inv, Cf, nt],
-                          axis=-1)
-    keys = _key_data(keys)
+    n = C.shape[0]
+    n_pad = _round_up(n, LANES)
+    block_t = _clamp_block(T, block_t, n_pad)
     if (psrv is None) != (pbytes is None):
         raise ValueError("psrv and pbytes must be given together")
-    pad = (-T) % block_t
-    if pad:
-        # Same inert-padding argument as dodoor_fused: zero demand is
-        # always feasible, so padded rows never flip the fallback branch.
-        keys = jnp.pad(keys, ((0, pad), (0, 0)))
-        r = jnp.pad(r, ((0, pad), (0, 0)))
-        d_types = jnp.pad(d_types, ((0, pad), (0, 0)))
-    loc = ()
+    # The server table, transposed so that servers run along lanes: one
+    # row per field, padded to the 8-row sublane tile and to n_pad lanes.
+    # The kernel masks the padded servers out of every draw.
+    Cf = C.astype(jnp.float32)
+    inv = 1.0 / jnp.sum(Cf ** 2, axis=-1)
+    fields = ([L[:, c].astype(jnp.float32) for c in range(K)]
+              + [D.astype(jnp.float32), inv]
+              + [Cf[:, c] for c in range(K)]
+              + [node_type.astype(jnp.float32)])
+    tbl = jnp.stack(fields)
+    tbl = jnp.pad(tbl, ((0, (-tbl.shape[0]) % 8), (0, n_pad - n)))
+
+    def rows(x, value=0):
+        # Padded task rows run through the full pipeline and are sliced
+        # away: zero keys and demand, every server available, no parents.
+        pad = (-T) % block_t
+        return jnp.pad(x, ((0, pad), (0, 0)), constant_values=value)
+
+    operands = dict(avail=None, psrv=None, pbytes=None)
+    if avail is not None:
+        avail = jnp.pad(avail.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
+        operands["avail"] = rows(avail, 1.0)
     if psrv is not None:
-        psrv = psrv.astype(jnp.int32)
-        pbytes = pbytes.astype(jnp.float32)
-        if pad:
-            # Padded tasks get no parents (-1 ids, zero bytes → zero
-            # penalty), like the zero-demand rows above.
-            psrv = jnp.pad(psrv, ((0, pad), (0, 0)), constant_values=-1)
-            pbytes = jnp.pad(pbytes, ((0, pad), (0, 0)))
-        loc = (psrv, pbytes)
-    if avail is None:
-        choice, cand, scores = dodoor_fused_sparse_pallas(
-            keys, r.astype(jnp.float32), d_types.astype(jnp.float32), tbl,
-            *loc, alpha=alpha, gamma_bw=float(gamma_bw), block_t=block_t,
-            interpret=interpret)
-    else:
-        avail = avail.astype(jnp.float32)
-        if pad:
-            avail = jnp.pad(avail, ((0, pad), (0, 0)),
-                            constant_values=1.0)
-        choice, cand, scores = dodoor_fused_sparse_masked_pallas(
-            keys, r.astype(jnp.float32), d_types.astype(jnp.float32),
-            avail, tbl, *loc, alpha=alpha, gamma_bw=float(gamma_bw),
-            block_t=block_t, interpret=interpret)
-    return choice[:T], cand[:T], scores[:T]
+        operands["psrv"] = rows(psrv.astype(jnp.int32), -1)
+        operands["pbytes"] = rows(pbytes.astype(jnp.float32))
+    choice, cand, scores = dodoor_fused_sparse_pallas(
+        rows(_key_data(keys)), rows(r.astype(jnp.float32)),
+        rows(d_types.astype(jnp.float32)), tbl, **operands, n=n,
+        alpha=alpha, gamma_bw=float(gamma_bw), block_t=block_t,
+        interpret=interpret)
+    return choice[:T, 0], cand[:T], scores[:T]

@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .ops import _clamp_block, dodoor_fused_sparse
+from .kernel import LANES
+from .ops import _clamp_block, _round_up, dodoor_fused_sparse
 
 DEFAULT_CANDIDATES = (64, 128, 256, 512)
 
@@ -62,7 +63,7 @@ def autotune_block_t(T: int, N: int, *, TT: int = 4,
     curve = []
     timed: dict[int, float] = {}          # effective tile -> ms
     for bt in candidates:
-        eff = _clamp_block(T, bt)
+        eff = _clamp_block(T, bt, _round_up(N, LANES))
         if eff not in timed:
             def run(bt=bt):
                 choice, _, _ = dodoor_fused_sparse(

@@ -91,6 +91,13 @@ class ArrivalRing:
         self._count += k
         return k
 
+    def zeros(self, k: int) -> ArrivalRows:
+        """``k`` all-zero rows shaped like the ring's slots."""
+        return ArrivalRows(*(np.zeros((k,) + buf.shape[1:], buf.dtype)
+                             for buf in (self._r_submit, self._r_exec,
+                                         self._d_est, self._d_act,
+                                         self._submit_ms, self._t_enq)))
+
     def pop(self, k: int) -> ArrivalRows:
         """Remove and return the oldest ``k`` rows (copies)."""
         if k < 1 or k > self._count:

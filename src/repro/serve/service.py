@@ -223,23 +223,49 @@ class DecisionService:
         self._ring_pad += pad
         return done + self._run_block(padded, k)
 
-    def _run_block(self, rows: ArrivalRows, valid_count: int) -> int:
+    def _block(self, rows: ArrivalRows, valid_count: int) -> tuple:
+        """The step's block operand: global decision ids, the planes, and
+        the validity mask."""
         b = self._b
-        t0 = time.perf_counter()
         ids = np.arange(self._next_idx, self._next_idx + b,
                         dtype=np.int32)
         ids_dev = jnp.asarray(ids)
         mask = np.zeros((b,), bool)
         mask[:valid_count] = True
-        blk = (ids_dev, jnp.asarray(rows.r_submit),
-               jnp.asarray(rows.r_exec), jnp.asarray(rows.d_est),
-               jnp.asarray(rows.d_act), jnp.asarray(rows.submit_ms),
-               ids_dev, jnp.asarray(mask))
-        self._carry, out = _serve_step(
-            self._carry, blk, self._C, self._node_type, self._mem_unit,
-            self._cores_per, self._dyn, self._dyn_ints, self._win,
-            self._base_key, self._scfg, self._n, self._use_kernel,
-            self._masked, self._faulted)
+        return (ids_dev, jnp.asarray(rows.r_submit),
+                jnp.asarray(rows.r_exec), jnp.asarray(rows.d_est),
+                jnp.asarray(rows.d_act), jnp.asarray(rows.submit_ms),
+                ids_dev, jnp.asarray(mask))
+
+    def _step_operands(self, blk) -> tuple:
+        return (self._carry, blk, self._C, self._node_type, self._mem_unit,
+                self._cores_per, self._dyn, self._dyn_ints, self._win,
+                self._base_key)
+
+    def _step_statics(self) -> dict:
+        return dict(cfg=self._scfg, n=self._n, use_kernel=self._use_kernel,
+                    kernel_masked=self._masked, cache_faulted=self._faulted)
+
+    def lower_step(self, sharding=None):
+        """Lower the step program for this service's block shapes, to
+        inspect what the compiler emits (``.compile().as_text()``).  With
+        a ``sharding`` the operands are abstract shapes placed there — a
+        device of a described topology compiles for a chip that is not
+        attached."""
+        operands = self._step_operands(
+            self._block(self._ring.zeros(self._b), self._b))
+        if sharding is not None:
+            operands = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                               sharding=sharding), operands)
+        return _serve_step.lower(*operands, **self._step_statics())
+
+    def _run_block(self, rows: ArrivalRows, valid_count: int) -> int:
+        b = self._b
+        t0 = time.perf_counter()
+        blk = self._block(rows, valid_count)
+        self._carry, out = _serve_step(*self._step_operands(blk),
+                                       **self._step_statics())
         jax.block_until_ready(out)
         t1 = time.perf_counter()
         self.step_wall.record((t1 - t0) * 1e3)

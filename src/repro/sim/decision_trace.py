@@ -62,7 +62,7 @@ def _load_score_np(r, L_ab, D_ab, C_ab, alpha):
     D_ab = D_ab.astype(np.float32)
     C_ab = C_ab.astype(np.float32)
     alpha = np.float32(alpha)
-    rl_ab = (np.einsum("tk,tck->tc", r, L_ab)
+    rl_ab = (np.sum(r[:, None, :] * L_ab, axis=-1)
              / np.sum(C_ab * C_ab, axis=-1)).astype(np.float32)
     rl_sum = np.sum(rl_ab, axis=-1, keepdims=True)
     d_sum = np.sum(D_ab, axis=-1, keepdims=True)

@@ -1,10 +1,10 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see the single real CPU device; only launch/dryrun.py forces 512 devices.
 
-Suite-speed plumbing (ISSUE 1):
-* a persistent XLA compilation cache under ``.jax_cache/`` (compiles
-  dominate the wall clock; re-runs skip them) — set via env *before* the
-  first ``import jax`` anywhere in the session;
+Suite-speed plumbing:
+* the persistent XLA compilation cache (compiles dominate the wall clock;
+  re-runs skip them), placed by ``repro.compile_cache`` — set via env
+  *before* the first ``import jax`` anywhere in the session;
 * ``sim_cache`` — session-scope memoization of ``simulate()`` results so
   modules sharing a (workload, cluster, config) triple simulate once.
 """
@@ -13,10 +13,9 @@ import os
 import numpy as np
 import pytest
 
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache"))
+from repro.compile_cache import use_compile_cache
+
+use_compile_cache()
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 

@@ -1,0 +1,76 @@
+"""Compile the served path's TPU programs for a described v5e chip.
+
+Nothing runs: the TPU compiler that ships with jaxlib lowers each program
+for a chip that is described, not attached, and raises what Mosaic or XLA
+would raise on the chip (unsupported primitives, illegal block shapes,
+scoped-VMEM overflow).  The sparse megakernel is compiled alone, unmasked
+and masked, at the decision-block and fleet widths users run, and inside
+the jitted ``_serve_step`` for ``dodoor`` at the testbed width and at
+n = 10⁴.  Each compiled program must contain the Mosaic kernel
+(``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, so that only the
+test process that runs this file loads the TPU library.  The persistent
+compilation cache is off around these compiles: an entry compiled for a
+described chip cannot be read back without one.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dodoor_choice import dodoor_fused_sparse
+from repro.serve import DecisionService
+from repro.sim import EngineConfig, make_scaled, make_testbed
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("masked", (False, True))
+@pytest.mark.parametrize("T,N", [(56, 100), (56, 10_000), (512, 100),
+                                 (512, 10_000)])
+def test_sparse_kernel_compiles(one_chip, T, N, masked):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(keys, r, dt, nt, L, D, C, avail=None):
+        return dodoor_fused_sparse(keys, r, dt, nt, L, D, C, 0.5,
+                                   avail=avail, interpret=False)
+
+    args = [S((T, 2), jnp.uint32), S((T, 2), jnp.float32),
+            S((T, 4), jnp.float32), S((N,), jnp.int32),
+            S((N, 2), jnp.float32), S((N,), jnp.float32),
+            S((N, 2), jnp.float32)]
+    kw = {"avail": S((T, N), jnp.bool_)} if masked else {}
+    compiled = jax.jit(run).lower(*args, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("masked", (False, True))
+@pytest.mark.parametrize("n,b", [(100, 50), (10_000, 500)])
+def test_serve_step_compiles_with_kernel(one_chip, n, b, masked):
+    """The dodoor step the service jits, with the kernel compiled (not
+    interpreted), at the paper testbed and at the n = 10⁴ scale point;
+    ``masked`` is the form that down windows select."""
+    cluster = make_testbed() if n == 100 else make_scaled(n)
+    assert cluster.num_servers == n
+    cfg = EngineConfig(policy="dodoor", b=b, interpret=False)
+    svc = DecisionService(cluster, cfg, use_kernel=True, capacity=b)
+    compiled = svc.lower_step(one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -282,6 +282,21 @@ class TestDodoorFusedSparseMegakernel:
         assert (np.asarray(c0) == np.asarray(c1)).all()
         np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
 
+    @pytest.mark.parametrize("typed", (False, True))
+    def test_uniform_pair_is_jax_uniform(self, typed):
+        """The kernel's two uniforms are ``jax.random.uniform(k, (2,))``
+        bit for bit, for raw uint32 and typed keys, under the installed
+        JAX's default threefry layout."""
+        from repro.kernels.dodoor_choice.kernel import _uniform_pair
+        make = jax.random.key if typed else jax.random.PRNGKey
+        keys = jax.vmap(lambda i: jax.random.fold_in(make(3), i))(
+            jnp.arange(64))
+        words = jax.random.key_data(keys) if typed else keys
+        u0, u1 = _uniform_pair(words[:, 0], words[:, 1])
+        want = jax.vmap(lambda k: jax.random.uniform(k, (2,)))(keys)
+        np.testing.assert_array_equal(np.stack([u0, u1], axis=1),
+                                      np.asarray(want))
+
     def test_draws_pinned_to_two_stage_sampler(self):
         """The in-kernel draws ARE sample_feasible_batch's — the ISSUE 6
         acceptance pin at n ≤ 10³."""
