@@ -1,0 +1,4 @@
+"""The benchmark's yardstick: fleet and workload synthesis, the traffic
+generator, latency arithmetic, trace reduction, the kernel bytes model and
+the comparison that decides ``correct``.  None of it imports the program
+except :mod:`harness.system`, which wraps the served path under test."""

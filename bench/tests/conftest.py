@@ -1,0 +1,10 @@
+"""The benchmark's own tests: ``python -m pytest bench/tests`` from the
+checkout's root.  They run at CPU size and never need the chip."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
