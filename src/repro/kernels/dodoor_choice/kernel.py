@@ -549,6 +549,7 @@ def dodoor_fused_sparse_pallas(keys, r, d_types, tbl, avail=None, psrv=None,
         grid=(T // block_t,),
         in_specs=in_specs,
         out_specs=[rows(1), rows(2), rows(2)],
+        name="dodoor_fused_sparse",
         out_shape=[
             jax.ShapeDtypeStruct((T, 1), jnp.int32),
             jax.ShapeDtypeStruct((T, 2), jnp.int32),

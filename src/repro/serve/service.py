@@ -25,6 +25,15 @@ Cache snapshots are double-buffered per §3.2: each block boundary
 publishes the post-push cached view into the non-live host buffer and
 flips the pointer, so :meth:`DecisionService.snapshot` readers always
 see a complete snapshot while the next block writes the other one.
+
+Every phase of the host round trip is a ``jax.profiler.TraceAnnotation``
+named ``serve.*`` and tagged ``block=k`` (block ``k`` holds decision ids
+``[k·b, (k+1)·b)``): ``serve.submit`` around each ring push, and per
+block one ``serve.step`` (or ``serve.flush`` for the ragged tail) holding
+``serve.ring_pop``, ``serve.upload``, ``serve.dispatch``,
+``serve.device_wait``, ``serve.readback`` and ``serve.publish``, in that
+order.  They land on a profiler trace beside the device's operations and
+cost about a microsecond each when no profiler runs.
 """
 from __future__ import annotations
 
@@ -34,6 +43,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..sim.cluster import ClusterSpec
 from ..sim.engine import (Dynamics, EngineConfig, SimResult, _Carry,
@@ -143,6 +153,7 @@ class DecisionService:
         self._steps = 0
         self._outs: list[list[np.ndarray]] = [[] for _ in range(8)]
         self.decision_latency = LatencyRecorder()
+        self.ring_wait = LatencyRecorder()
         self.step_wall = LatencyRecorder()
         self._publish = publish_snapshots
         self._snaps: list[dict | None] = [None, None]
@@ -169,9 +180,12 @@ class DecisionService:
     def submit(self, r_submit, r_exec, d_est, d_act, submit_ms) -> int:
         """Enqueue an arrival chunk (numpy planes, any length ≥ 0).
         Records one host enqueue timestamp for the chunk — the start of
-        each task's enqueue→placement latency."""
-        return self._ring.push(r_submit, r_exec, d_est, d_act, submit_ms,
-                               time.perf_counter())
+        each task's enqueue→placement latency.  The ``serve.submit`` span's
+        ``block`` is the block the chunk's first task joins."""
+        with TraceAnnotation("serve.submit", block=(
+                self._next_idx + self._ring.count) // self._b):
+            return self._ring.push(r_submit, r_exec, d_est, d_act,
+                                   submit_ms, time.perf_counter())
 
     def submit_workload(self, workload, start: int = 0,
                         stop: int | None = None) -> int:
@@ -192,8 +206,11 @@ class DecisionService:
             raise ValueError(
                 f"step() needs a full block: {self._ring.count} buffered "
                 f"< b={b}; submit more, or flush() the ragged tail")
-        rows = self._ring.pop(b)
-        return self._run_block(rows, b)
+        block = self._steps
+        with TraceAnnotation("serve.step", block=block):
+            with TraceAnnotation("serve.ring_pop", block=block):
+                rows = self._ring.pop(b)
+            return self._run_block(rows, b, block)
 
     def drain(self) -> int:
         """Step every full block currently buffered; returns tasks
@@ -212,16 +229,19 @@ class DecisionService:
         k = self._ring.count
         if k == 0:
             return done
-        rows = self._ring.pop(k)
         pad = self._b - k
 
         def edge(a):
             return np.concatenate(
                 [a, np.repeat(a[-1:], pad, axis=0)], axis=0)
 
-        padded = ArrivalRows(*(edge(np.asarray(p)) for p in rows))
-        self._ring_pad += pad
-        return done + self._run_block(padded, k)
+        block = self._steps
+        with TraceAnnotation("serve.flush", block=block):
+            with TraceAnnotation("serve.ring_pop", block=block):
+                rows = self._ring.pop(k)
+                padded = ArrivalRows(*(edge(np.asarray(p)) for p in rows))
+            self._ring_pad += pad
+            return done + self._run_block(padded, k, block)
 
     def _block(self, rows: ArrivalRows, valid_count: int) -> tuple:
         """The step's block operand: global decision ids, the planes, and
@@ -260,32 +280,40 @@ class DecisionService:
                                                sharding=sharding), operands)
         return _serve_step.lower(*operands, **self._step_statics())
 
-    def _run_block(self, rows: ArrivalRows, valid_count: int) -> int:
+    def _run_block(self, rows: ArrivalRows, valid_count: int, k: int) -> int:
+        """Block ``k``'s round trip, one ``serve.*`` span per phase."""
         b = self._b
         t0 = time.perf_counter()
-        blk = self._block(rows, valid_count)
-        self._carry, out = _serve_step(*self._step_operands(blk),
-                                       **self._step_statics())
-        jax.block_until_ready(out)
+        with TraceAnnotation("serve.upload", block=k):
+            blk = self._block(rows, valid_count)
+        t_dispatch = time.perf_counter()
+        with TraceAnnotation("serve.dispatch", block=k):
+            self._carry, out = _serve_step(*self._step_operands(blk),
+                                           **self._step_statics())
+        with TraceAnnotation("serve.device_wait", block=k):
+            jax.block_until_ready(out)
         t1 = time.perf_counter()
         self.step_wall.record((t1 - t0) * 1e3)
-        self.decision_latency.record(
-            (t1 - rows.t_enq[:valid_count]) * 1e3)
-        for acc, plane in zip(self._outs[:7], out):
-            acc.append(np.asarray(plane)[:valid_count])
+        t_enq = rows.t_enq[:valid_count]
+        self.ring_wait.record((t_dispatch - t_enq) * 1e3)
+        self.decision_latency.record((t1 - t_enq) * 1e3)
+        with TraceAnnotation("serve.readback", block=k):
+            for acc, plane in zip(self._outs[:7], out):
+                acc.append(np.asarray(plane)[:valid_count])
         self._outs[7].append(rows.submit_ms[:valid_count])
         self._next_idx += b
         self._steps += 1
         if self._publish:
-            idx = self._steps % 2
-            self._snaps[idx] = {
-                "step": self._steps,
-                "virtual_ms": float(rows.submit_ms[valid_count - 1]),
-                "view_L": np.asarray(self._carry.view_L),
-                "view_D": np.asarray(self._carry.view_D),
-                "view_rif": np.asarray(self._carry.view_rif),
-            }
-            self._live = idx
+            with TraceAnnotation("serve.publish", block=k):
+                idx = self._steps % 2
+                self._snaps[idx] = {
+                    "step": self._steps,
+                    "virtual_ms": float(rows.submit_ms[valid_count - 1]),
+                    "view_L": np.asarray(self._carry.view_L),
+                    "view_D": np.asarray(self._carry.view_D),
+                    "view_rif": np.asarray(self._carry.view_rif),
+                }
+                self._live = idx
         return valid_count
 
     # -- results ----------------------------------------------------------
@@ -318,10 +346,12 @@ class DecisionService:
             policy=self.cfg.policy)
 
     def latency_summary(self) -> dict:
-        """Histograms + percentiles for both instrumented clocks."""
+        """Histograms + percentiles for the instrumented clocks."""
         return {
             "decision": {**self.decision_latency.summary(),
                          "histogram": self.decision_latency.histogram()},
+            "ring_wait": {**self.ring_wait.summary(),
+                          "histogram": self.ring_wait.histogram()},
             "step": {**self.step_wall.summary(),
                      "histogram": self.step_wall.histogram()},
         }

@@ -1160,6 +1160,13 @@ def _make_block_step(C, node_type, mem_unit, cores_per, dyn_vec, dyn_ints,
     bit-exact with the offline scan: the offline engine is the
     correctness oracle for the online one.  ``base_key`` is the
     ``jax.random.PRNGKey(seed)`` each task's decision key folds into.
+
+    The body runs in three ``jax.named_scope``s: ``select`` (keys,
+    availability, feasibility and the policy's choice), ``commit`` (the
+    commit rounds, PoT's speculative loop, Prequal's segment scan) and
+    ``push`` (deltas, flushes, the store push and the message ledger).
+    They name the compiled instructions' ``op_name`` metadata, which is
+    how a device trace's ops are mapped to stages; no arithmetic changes.
     """
     dyn = _Dyn(*dyn_vec)
     fe_dyn = dyn_ints[1]                 # flush cadence is traced; b shapes
@@ -1178,354 +1185,364 @@ def _make_block_step(C, node_type, mem_unit, cores_per, dyn_vec, dyn_ints,
             idx, r_sub, r_exec_t, d_est_t, d_act_t, submit, task_id, valid \
                 = blk
             psrv = pbytes = None
-        bsz = idx.shape[0]
-        tt = jnp.arange(bsz, dtype=jnp.int32)
-        now = submit                                            # [b]
-        sched = (idx % S).astype(jnp.int32)
-        keys = jax.vmap(lambda t: jax.random.fold_in(base_key, t))(task_id)
-        # Durations stay factorized as d_est_t [b, num_types] + the
-        # server→type map; every consumer gathers per type, so no dense
-        # [b, n] duration plane is ever materialized (the operand that
-        # collapsed decisions/s above 10⁴ servers).  d_est_t[t, nt[j]] is
-        # the same float the old plane held — placements are unchanged.
-        avail = _avail_rows(win, now)                           # [b, n]
-        mask = feasible_mask(r_sub, C) & avail                  # [b, n]
+        with jax.named_scope("select"):
+            bsz = idx.shape[0]
+            tt = jnp.arange(bsz, dtype=jnp.int32)
+            now = submit                                        # [b]
+            sched = (idx % S).astype(jnp.int32)
+            keys = jax.vmap(lambda t: jax.random.fold_in(base_key, t))(task_id)
+            # Durations stay factorized as d_est_t [b, num_types] + the
+            # server→type map; every consumer gathers per type, so no dense
+            # [b, n] duration plane is ever materialized (the operand that
+            # collapsed decisions/s above 10⁴ servers).  d_est_t[t, nt[j]] is
+            # the same float the old plane held — placements are unchanged.
+            avail = _avail_rows(win, now)                           # [b, n]
+            mask = feasible_mask(r_sub, C) & avail                  # [b, n]
 
-        # ---- vectorized selection against the block's one cache snapshot
-        extra_lat = jnp.zeros((bsz,), jnp.float32)
-        probe_msgs = 0
-        if policy == "random":
-            j = sample_feasible_batch(keys, mask, 1)[:, 0]
-        elif policy in ("dodoor", "one_plus_beta"):
-            kk = jax.vmap(jax.random.split)(keys)               # [b, 2, key]
-            k_cand, k_beta = kk[:, 0], kk[:, 1]
-            if use_kernel:
-                # Sparse-gather megakernel: candidate sampling, Algorithm-1
-                # scoring and selection in one Pallas pass over the
-                # factorized duration table (α/block_t/interpret are static
-                # program knobs baked into the grid program).  Under
-                # down-window timelines the availability plane rides into
-                # the in-kernel prefilter, so scenarios are honored with
-                # draws bit-identical to the two-stage masked path.
-                two, cand2, _ = dodoor_fused_sparse(
-                    k_cand, r_sub, d_est_t, node_type, carry.view_L,
-                    carry.view_D, C, alpha=cfg.alpha,
-                    avail=avail if kernel_masked else None,
-                    psrv=psrv, pbytes=pbytes,
-                    gamma_bw=(cfg.locality.gamma_bw
-                              if locality and cfg.locality is not None
-                              else 0.0),
-                    block_t=cfg.block_t, interpret=cfg.interpret)
-            elif cache_faulted:
-                # Per-scheduler degraded views: gather each task's own
-                # scheduler's copy, then the same Algorithm-1 arithmetic
-                # as dodoor_choice_batch (bit-exact vs the sequential
-                # faulted read).
-                cand2 = sample_feasible_batch(k_cand, mask, 2)  # [b, 2]
-                d_cand = d_est_t[tt[:, None], node_type[cand2]]
-                L_c = carry.view_L[sched[:, None], cand2]       # [b, 2, 2]
-                D_c = carry.view_D[sched[:, None], cand2] + d_cand
-                scores = load_score_batched(r_sub, L_c, D_c, C[cand2],
-                                            dyn.alpha)
-                if locality:
+            # ---- vectorized selection against the block's one cache snapshot
+            extra_lat = jnp.zeros((bsz,), jnp.float32)
+            probe_msgs = 0
+            if policy == "random":
+                j = sample_feasible_batch(keys, mask, 1)[:, 0]
+            elif policy in ("dodoor", "one_plus_beta"):
+                kk = jax.vmap(jax.random.split)(keys)           # [b, 2, key]
+                k_cand, k_beta = kk[:, 0], kk[:, 1]
+                if use_kernel:
+                    # Sparse-gather megakernel: candidate sampling, Algorithm-1
+                    # scoring and selection in one Pallas pass over the
+                    # factorized duration table (α/block_t/interpret are
+                    # static program knobs baked into the grid program).  Under
+                    # down-window timelines the availability plane rides into
+                    # the in-kernel prefilter, so scenarios are honored with
+                    # draws bit-identical to the two-stage masked path.
+                    two, cand2, _ = dodoor_fused_sparse(
+                        k_cand, r_sub, d_est_t, node_type, carry.view_L,
+                        carry.view_D, C, alpha=cfg.alpha,
+                        avail=avail if kernel_masked else None,
+                        psrv=psrv, pbytes=pbytes,
+                        gamma_bw=(cfg.locality.gamma_bw
+                                  if locality and cfg.locality is not None
+                                  else 0.0),
+                        block_t=cfg.block_t, interpret=cfg.interpret)
+                elif cache_faulted:
+                    # Per-scheduler degraded views: gather each task's own
+                    # scheduler's copy, then the same Algorithm-1 arithmetic
+                    # as dodoor_choice_batch (bit-exact vs the sequential
+                    # faulted read).
+                    cand2 = sample_feasible_batch(k_cand, mask, 2)  # [b, 2]
+                    d_cand = d_est_t[tt[:, None], node_type[cand2]]
+                    L_c = carry.view_L[sched[:, None], cand2]       # [b, 2, 2]
+                    D_c = carry.view_D[sched[:, None], cand2] + d_cand
+                    scores = load_score_batched(r_sub, L_c, D_c, C[cand2],
+                                                dyn.alpha)
+                    if locality:
+                        rem = jnp.sum(
+                            pbytes[:, None, :]
+                            * (psrv[:, None, :] != cand2[:, :, None]
+                               ).astype(jnp.float32), axis=-1)      # [b, 2]
+                        scores = scores + dyn.gamma_bw * rem
+                    two = jnp.where(scores[:, 0] > scores[:, 1],
+                                    cand2[:, 1], cand2[:, 0])
+                elif locality:
+                    # Same arithmetic as dodoor_choice_batch, inlined so the
+                    # locality penalty lands between scoring and selection —
+                    # order-identical to the sequential _select path.
+                    cand2 = sample_feasible_batch(k_cand, mask, 2)  # [b, 2]
+                    d_cand = d_est_t[tt[:, None], node_type[cand2]]
+                    L_c = carry.view_L[cand2]                       # [b, 2, 2]
+                    D_c = carry.view_D[cand2] + d_cand
+                    scores = load_score_batched(r_sub, L_c, D_c, C[cand2],
+                                                dyn.alpha)
                     rem = jnp.sum(
                         pbytes[:, None, :]
                         * (psrv[:, None, :] != cand2[:, :, None]
-                           ).astype(jnp.float32), axis=-1)      # [b, 2]
+                           ).astype(jnp.float32), axis=-1)          # [b, 2]
                     scores = scores + dyn.gamma_bw * rem
-                two = jnp.where(scores[:, 0] > scores[:, 1],
-                                cand2[:, 1], cand2[:, 0])
-            elif locality:
-                # Same arithmetic as dodoor_choice_batch, inlined so the
-                # locality penalty lands between scoring and selection —
-                # order-identical to the sequential _select path.
-                cand2 = sample_feasible_batch(k_cand, mask, 2)  # [b, 2]
-                d_cand = d_est_t[tt[:, None], node_type[cand2]]
-                L_c = carry.view_L[cand2]                       # [b, 2, 2]
-                D_c = carry.view_D[cand2] + d_cand
-                scores = load_score_batched(r_sub, L_c, D_c, C[cand2],
-                                            dyn.alpha)
-                rem = jnp.sum(
-                    pbytes[:, None, :]
-                    * (psrv[:, None, :] != cand2[:, :, None]
-                       ).astype(jnp.float32), axis=-1)          # [b, 2]
-                scores = scores + dyn.gamma_bw * rem
-                two = jnp.where(scores[:, 0] > scores[:, 1],
-                                cand2[:, 1], cand2[:, 0])
+                    two = jnp.where(scores[:, 0] > scores[:, 1],
+                                    cand2[:, 1], cand2[:, 0])
+                else:
+                    cand2 = sample_feasible_batch(k_cand, mask, 2)  # [b, 2]
+                    d_cand = d_est_t[tt[:, None], node_type[cand2]]
+                    view = SchedulerView(L=carry.view_L, D=carry.view_D,
+                                         rif=carry.view_rif, C=C)
+                    two = dodoor_choice_batch(r_sub, cand2, d_cand, view,
+                                              dyn.alpha, use_kernel=False)
+                if policy == "one_plus_beta":
+                    u = jax.vmap(jax.random.uniform)(k_beta)
+                    j = jnp.where(u < dyn.beta, two,
+                                  cand2[:, 0]).astype(jnp.int32)
+                else:
+                    j = two.astype(jnp.int32)
+                extra_lat = jnp.maximum(0.0, carry.push_end - now)
+                if trace:
+                    # Capture only what the scan alone knows — the cached-rif
+                    # reads and the sampled candidates.  Ground truth is
+                    # rebuilt post-scan from the commit history
+                    # (repro.sim.decision_trace), so tracing adds no per-step
+                    # gather/reduce work.  No extra RNG is consumed —
+                    # placements are unchanged.
+                    v_rif = (carry.view_rif[sched[:, None], cand2]
+                             if cache_faulted else carry.view_rif[cand2])
+                    age_t = now - carry.push_at[sched]          # [b]
+                    use_two_t = ((u < dyn.beta).astype(jnp.float32)
+                                 if policy == "one_plus_beta"
+                                 else jnp.ones((bsz,), jnp.float32))
+            elif policy == "pot":
+                probe_msgs = 4
+                cand = sample_feasible_batch(keys, mask, 2)         # [b, 2]
+            elif policy == "prequal":
+                PP = cfg.prequal
+                probe_msgs = 2 * PP.r_probe
+                P = PP.s_pool
+                kk3 = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
+                rand_j = sample_feasible_batch(kk3[:, 1], mask, 1)[:, 0]
+                probes = jax.vmap(lambda k: jax.random.randint(
+                    k, (PP.r_probe,), 0, n))(kk3[:, 2])             # [b, rp]
             else:
-                cand2 = sample_feasible_batch(k_cand, mask, 2)  # [b, 2]
-                d_cand = d_est_t[tt[:, None], node_type[cand2]]
-                view = SchedulerView(L=carry.view_L, D=carry.view_D,
-                                     rif=carry.view_rif, C=C)
-                two = dodoor_choice_batch(r_sub, cand2, d_cand, view,
-                                          dyn.alpha, use_kernel=False)
-            if policy == "one_plus_beta":
-                u = jax.vmap(jax.random.uniform)(k_beta)
-                j = jnp.where(u < dyn.beta, two, cand2[:, 0]).astype(jnp.int32)
-            else:
-                j = two.astype(jnp.int32)
-            extra_lat = jnp.maximum(0.0, carry.push_end - now)
-            if trace:
-                # Capture only what the scan alone knows — the cached-rif
-                # reads and the sampled candidates.  Ground truth is
-                # rebuilt post-scan from the commit history
-                # (repro.sim.decision_trace), so tracing adds no per-step
-                # gather/reduce work.  No extra RNG is consumed —
-                # placements are unchanged.
-                v_rif = (carry.view_rif[sched[:, None], cand2]
-                         if cache_faulted else carry.view_rif[cand2])
-                age_t = now - carry.push_at[sched]          # [b]
-                use_two_t = ((u < dyn.beta).astype(jnp.float32)
-                             if policy == "one_plus_beta"
-                             else jnp.ones((bsz,), jnp.float32))
-        elif policy not in ("pot", "prequal"):
-            raise ValueError(f"policy {policy!r} has no batched driver")
+                raise ValueError(f"policy {policy!r} has no batched path")
 
         # ---- commit
-        if policy in ("random", "dodoor", "one_plus_beta"):
-            nt_j = node_type[j]                                 # [b]
-            cores_t = r_exec_t[tt, nt_j, 0]
-            mem_t = r_exec_t[tt, nt_j, 1]
-            dur_t = d_act_t[tt, nt_j]
-            dest_t = d_est_t[tt, nt_j]
-            carry, outs = _commit_rounds(
-                carry, valid, now, j, cores_t, mem_t, dur_t, dest_t,
-                extra_lat, dyn, win, cores_per, mem_unit, n, MU,
-                retry=retry)
-        elif policy == "pot":
-            # Speculative commit + conflict replay.  Each iteration scores
-            # every pending task against the *current* carry, commits the
-            # longest conflict-free prefix in parallel rounds, and loops on
-            # the suffix.  Safety rule: a pending task conflicts iff an
-            # earlier pending task's speculative placement hits one of its
-            # two probed candidates — so within a committed prefix every
-            # probe read equals the sequential ground truth (and prefix
-            # placements are pairwise distinct, making the commit 1 round).
-            probe_msgs = 4
-            cand = sample_feasible_batch(keys, mask, 2)         # [b, 2]
-            nt_c = node_type[cand]                              # [b, 2]
-            cores_c = r_exec_t[tt[:, None], nt_c, 0]
-            mem_c = r_exec_t[tt[:, None], nt_c, 1]
-            dur_c = d_act_t[tt[:, None], nt_c]
-            dest_c = d_est_t[tt[:, None], nt_c]
-            pot_lat = jnp.broadcast_to(2.0 * dyn.hop_ms, (bsz,))
+        with jax.named_scope("commit"):
+            if policy in ("random", "dodoor", "one_plus_beta"):
+                nt_j = node_type[j]                                 # [b]
+                cores_t = r_exec_t[tt, nt_j, 0]
+                mem_t = r_exec_t[tt, nt_j, 1]
+                dur_t = d_act_t[tt, nt_j]
+                dest_t = d_est_t[tt, nt_j]
+                carry, outs = _commit_rounds(
+                    carry, valid, now, j, cores_t, mem_t, dur_t, dest_t,
+                    extra_lat, dyn, win, cores_per, mem_unit, n, MU,
+                    retry=retry)
+            elif policy == "pot":
+                # Speculative commit + conflict replay.  Each iteration scores
+                # every pending task against the *current* carry, commits the
+                # longest conflict-free prefix in parallel rounds, and loops on
+                # the suffix.  Safety rule: a pending task conflicts iff an
+                # earlier pending task's speculative placement hits one of its
+                # two probed candidates — so within a committed prefix every
+                # probe read equals the sequential ground truth (and prefix
+                # placements are pairwise distinct, making the commit 1 round).
+                nt_c = node_type[cand]                              # [b, 2]
+                cores_c = r_exec_t[tt[:, None], nt_c, 0]
+                mem_c = r_exec_t[tt[:, None], nt_c, 1]
+                dur_c = d_act_t[tt[:, None], nt_c]
+                dest_c = d_est_t[tt[:, None], nt_c]
+                pot_lat = jnp.broadcast_to(2.0 * dyn.hop_ms, (bsz,))
 
-            def spec_cond(state):
-                return state[0] < bsz
+                def spec_cond(state):
+                    return state[0] < bsz
 
-            def spec_body(state):
-                p, c, j_acc, outs = state
-                pending = (tt >= p) & valid
-                act = (c.rb_release[cand]
-                       > now[:, None, None]).astype(jnp.float32)
-                rif = jnp.sum(act, axis=-1)                     # [b, 2]
-                pick_b = rif[:, 1] < rif[:, 0]
-                j_spec = jnp.where(pick_b, cand[:, 1],
-                                   cand[:, 0]).astype(jnp.int32)
-                j_eff = jnp.where(pending, j_spec, n)           # sentinel
-                hit = ((j_eff[None, :] == cand[:, :1])
-                       | (j_eff[None, :] == cand[:, 1:]))       # [b, b]
-                unsafe = (jnp.any(hit & (tt[None, :] < tt[:, None]), axis=1)
-                          & pending)
-                q = jnp.min(jnp.where(unsafe, tt, bsz)).astype(jnp.int32)
-                commit = pending & (tt < q)
-                c, outs = _commit_rounds(
-                    c, commit, now, j_spec,
-                    jnp.where(pick_b, cores_c[:, 1], cores_c[:, 0]),
-                    jnp.where(pick_b, mem_c[:, 1], mem_c[:, 0]),
-                    jnp.where(pick_b, dur_c[:, 1], dur_c[:, 0]),
-                    jnp.where(pick_b, dest_c[:, 1], dest_c[:, 0]),
-                    pot_lat, dyn, win, cores_per, mem_unit, n, MU,
-                    outs0=outs, retry=retry)
-                j_acc = jnp.where(commit, j_spec, j_acc)
-                return (q, c, j_acc, outs)
+                def spec_body(state):
+                    p, c, j_acc, outs = state
+                    pending = (tt >= p) & valid
+                    act = (c.rb_release[cand]
+                           > now[:, None, None]).astype(jnp.float32)
+                    rif = jnp.sum(act, axis=-1)                     # [b, 2]
+                    pick_b = rif[:, 1] < rif[:, 0]
+                    j_spec = jnp.where(pick_b, cand[:, 1],
+                                       cand[:, 0]).astype(jnp.int32)
+                    j_eff = jnp.where(pending, j_spec, n)           # sentinel
+                    hit = ((j_eff[None, :] == cand[:, :1])
+                           | (j_eff[None, :] == cand[:, 1:]))       # [b, b]
+                    unsafe = (jnp.any(hit & (tt[None, :] < tt[:, None]),
+                                      axis=1) & pending)
+                    q = jnp.min(jnp.where(unsafe, tt, bsz)).astype(jnp.int32)
+                    commit = pending & (tt < q)
+                    c, outs = _commit_rounds(
+                        c, commit, now, j_spec,
+                        jnp.where(pick_b, cores_c[:, 1], cores_c[:, 0]),
+                        jnp.where(pick_b, mem_c[:, 1], mem_c[:, 0]),
+                        jnp.where(pick_b, dur_c[:, 1], dur_c[:, 0]),
+                        jnp.where(pick_b, dest_c[:, 1], dest_c[:, 0]),
+                        pot_lat, dyn, win, cores_per, mem_unit, n, MU,
+                        outs0=outs, retry=retry)
+                    j_acc = jnp.where(commit, j_spec, j_acc)
+                    return (q, c, j_acc, outs)
 
-            state = (jnp.int32(0), carry, jnp.zeros((bsz,), jnp.int32),
-                     jnp.zeros((orows, bsz), jnp.float32))
-            _, carry, j, outs = jax.lax.while_loop(spec_cond, spec_body,
+                state = (jnp.int32(0), carry, jnp.zeros((bsz,), jnp.int32),
+                         jnp.zeros((orows, bsz), jnp.float32))
+                _, carry, j, outs = jax.lax.while_loop(spec_cond, spec_body,
+                                                       state)
+            else:  # prequal — scheduler-parallel segment scan over S-chunks
+                nchunks = -(-bsz // S)
+                rows_s = jnp.arange(S, dtype=jnp.int32)
+                iota_P = jnp.arange(P, dtype=jnp.int32)[None, :]
+
+                def chunk_body(ci, state):
+                    c, j_acc, outs = state
+                    ic_raw = ci * S + rows_s
+                    ok = ic_raw < bsz
+                    ic = jnp.minimum(ic_raw, bsz - 1)
+                    m_c = ok & valid[ic]
+                    s_c = sched[ic]      # S consecutive tasks → S distinct
+                    now_c = now[ic]      # schedulers: pools are race-free
+                    s_eff = jnp.where(m_c, s_c, S)
+                    ic_eff = jnp.where(m_c, ic, bsz)
+
+                    # -- HCL selection from each scheduler's own pool.  Down
+                    #    servers' entries are skipped for selection (matching
+                    #    the sequential engine) but not deleted.
+                    avail_c = _avail_rows(win, now_c)               # [S, n]
+                    pv = c.pool_valid[s_c]                          # [S, P]
+                    pr = c.pool_rif[s_c]
+                    plat = c.pool_lat[s_c]
+                    pserv = c.pool_server[s_c]
+                    page = c.pool_age[s_c]
+                    pv_sel = pv & jnp.take_along_axis(avail_c, pserv, axis=1)
+                    rifs = jnp.where(pv_sel, pr, jnp.inf)
+                    lats = jnp.where(pv_sel, plat, jnp.inf)
+                    any_valid = jnp.any(pv_sel, axis=1)
+                    n_val = jnp.maximum(jnp.sum(pv_sel, axis=1), 1)
+                    sorted_rif = jnp.sort(rifs, axis=1)
+                    q_idx = jnp.clip(
+                        (dyn.q_rif * n_val.astype(jnp.float32)
+                         ).astype(jnp.int32),
+                        0, P - 1)
+                    threshold = jnp.take_along_axis(sorted_rif, q_idx[:, None],
+                                                    axis=1)[:, 0]
+                    cold = pv_sel & (pr <= threshold[:, None])
+                    cold_lat = jnp.where(cold, lats, jnp.inf)
+                    entry = jnp.where(jnp.any(cold, axis=1),
+                                      jnp.argmin(cold_lat, axis=1),
+                                      jnp.argmin(rifs, axis=1))
+                    j_c = jnp.where(any_valid, pserv[rows_s, entry],
+                                    rand_j[ic]).astype(jnp.int32)
+                    # b_reuse = 1: consume the used entry.
+                    pv = pv & ~(any_valid[:, None]
+                                & (iota_P == entry[:, None]))
+
+                    # -- commit the chunk (placements now known; FCFS rank
+                    #    within the chunk preserved by _commit_rounds' occ)
+                    commit = jnp.zeros((bsz,), bool).at[ic_eff].set(
+                        True, mode="drop")
+                    j_full = jnp.zeros((bsz,), jnp.int32).at[ic_eff].set(
+                        j_c, mode="drop")
+                    nt_c = node_type[j_c]
+
+                    def scat(v):
+                        return jnp.zeros((bsz,), v.dtype).at[ic_eff].set(
+                            v, mode="drop")
+
+                    c, outs = _commit_rounds(
+                        c, commit, now, j_full, scat(r_exec_t[ic, nt_c, 0]),
+                        scat(r_exec_t[ic, nt_c, 1]), scat(d_act_t[ic, nt_c]),
+                        scat(d_est_t[ic, nt_c]),
+                        jnp.zeros((bsz,), jnp.float32), dyn, win, cores_per,
+                        mem_unit, n, MU, outs0=outs, retry=retry)
+                    j_acc = jnp.where(commit, j_full, j_acc)
+
+                    # -- post-scheduling async probes: each task reads ground
+                    #    truth as of *its own* decision point.  The chunk
+                    #    committed first, so revert the rb slots written by
+                    #    same-chunk commits at or after each task — reverse-
+                    #    order (old, new) slot records telescope, exact even
+                    #    when commits collide on a server or slot.
+                    probes_c = probes[ic]                           # [S, rp]
+                    rel_rows = c.rb_release[probes_c]           # [S, rp, R]
+                    dur_rows = c.rb_dur[probes_c]
+                    for kloc in reversed(range(S)):
+                        col = ic[kloc]
+                        jk = j_full[col]
+                        slot_k = outs[6, col].astype(jnp.int32)
+                        do = (commit[col] & (rows_s <= kloc)[:, None]
+                              & (probes_c == jk))
+                        rel_rows = rel_rows.at[:, :, slot_k].set(
+                            jnp.where(do, outs[4, col],
+                                      rel_rows[:, :, slot_k]))
+                        dur_rows = dur_rows.at[:, :, slot_k].set(
+                            jnp.where(do, outs[5, col],
+                                      dur_rows[:, :, slot_k]))
+                    act = (rel_rows > now_c[:, None, None]).astype(jnp.float32)
+                    prif = jnp.sum(act, axis=-1)                    # [S, rp]
+                    pD = jnp.sum(dur_rows * act, axis=-1)
+
+                    # -- pool insert (sequential r_probe order) + maintenance;
+                    #    probes to down servers get no reply → no entry.
+                    avail_p = jnp.take_along_axis(avail_c, probes_c, axis=1)
+                    for ip in range(PP.r_probe):
+                        slot = jnp.argmin(jnp.where(pv, page, -jnp.inf),
+                                          axis=1)
+                        one = (iota_P == slot[:, None]) & avail_p[:, ip:ip + 1]
+                        pserv = jnp.where(one, probes_c[:, ip:ip + 1], pserv)
+                        pr = jnp.where(one, prif[:, ip:ip + 1], pr)
+                        plat = jnp.where(one, pD[:, ip:ip + 1], plat)
+                        page = jnp.where(
+                            one, (now_c + jnp.float32(ip) * 1e-3)[:, None],
+                            page)
+                        pv = jnp.where(one, True, pv)
+                    full = jnp.sum(pv, axis=1) >= P
+                    worst = jnp.argmax(jnp.where(pv, pr, -jnp.inf), axis=1)
+                    pv = pv & ~(full[:, None] & (iota_P == worst[:, None]))
+                    c = c._replace(
+                        pool_server=c.pool_server.at[s_eff].set(pserv,
+                                                                mode="drop"),
+                        pool_rif=c.pool_rif.at[s_eff].set(pr, mode="drop"),
+                        pool_lat=c.pool_lat.at[s_eff].set(plat, mode="drop"),
+                        pool_age=c.pool_age.at[s_eff].set(page, mode="drop"),
+                        pool_valid=c.pool_valid.at[s_eff].set(pv, mode="drop"),
+                    )
+                    return (c, j_acc, outs)
+
+                state = (carry, jnp.zeros((bsz,), jnp.int32),
+                         jnp.zeros((orows, bsz), jnp.float32))
+                carry, j, outs = jax.lax.fori_loop(0, nchunks, chunk_body,
                                                    state)
-        else:  # prequal — scheduler-parallel segment scan over S-chunks
-            PP = cfg.prequal
-            probe_msgs = 2 * PP.r_probe
-            P = PP.s_pool
-            kk3 = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
-            rand_j = sample_feasible_batch(kk3[:, 1], mask, 1)[:, 0]
-            probes = jax.vmap(lambda k: jax.random.randint(
-                k, (PP.r_probe,), 0, n))(kk3[:, 2])             # [b, rp]
-            nchunks = -(-bsz // S)
-            rows_s = jnp.arange(S, dtype=jnp.int32)
-            iota_P = jnp.arange(P, dtype=jnp.int32)[None, :]
 
-            def chunk_body(ci, state):
-                c, j_acc, outs = state
-                ic_raw = ci * S + rows_s
-                ok = ic_raw < bsz
-                ic = jnp.minimum(ic_raw, bsz - 1)
-                m_c = ok & valid[ic]
-                s_c = sched[ic]          # S consecutive tasks → S distinct
-                now_c = now[ic]          # schedulers: pools are race-free
-                s_eff = jnp.where(m_c, s_c, S)
-                ic_eff = jnp.where(m_c, ic, bsz)
+        with jax.named_scope("push"):
+            o_start, o_finish, o_enq, o_sched = (outs[0], outs[1], outs[2],
+                                                 outs[3])
+            if policy in ("pot", "prequal"):
+                nt_j = node_type[j]
+                cores_t = r_exec_t[tt, nt_j, 0]
+                mem_t = r_exec_t[tt, nt_j, 1]
+                dest_t = d_est_t[tt, nt_j]
 
-                # -- HCL selection from each scheduler's own pool.  Down
-                #    servers' entries are skipped for selection (matching
-                #    the sequential driver) but not deleted.
-                avail_c = _avail_rows(win, now_c)               # [S, n]
-                pv = c.pool_valid[s_c]                          # [S, P]
-                pr = c.pool_rif[s_c]
-                plat = c.pool_lat[s_c]
-                pserv = c.pool_server[s_c]
-                page = c.pool_age[s_c]
-                pv_sel = pv & jnp.take_along_axis(avail_c, pserv, axis=1)
-                rifs = jnp.where(pv_sel, pr, jnp.inf)
-                lats = jnp.where(pv_sel, plat, jnp.inf)
-                any_valid = jnp.any(pv_sel, axis=1)
-                n_val = jnp.maximum(jnp.sum(pv_sel, axis=1), 1)
-                sorted_rif = jnp.sort(rifs, axis=1)
-                q_idx = jnp.clip(
-                    (dyn.q_rif * n_val.astype(jnp.float32)).astype(jnp.int32),
-                    0, P - 1)
-                threshold = jnp.take_along_axis(sorted_rif, q_idx[:, None],
-                                                axis=1)[:, 0]
-                cold = pv_sel & (pr <= threshold[:, None])
-                cold_lat = jnp.where(cold, lats, jnp.inf)
-                entry = jnp.where(jnp.any(cold, axis=1),
-                                  jnp.argmin(cold_lat, axis=1),
-                                  jnp.argmin(rifs, axis=1))
-                j_c = jnp.where(any_valid, pserv[rows_s, entry],
-                                rand_j[ic]).astype(jnp.int32)
-                # b_reuse = 1: consume the used entry.
-                pv = pv & ~(any_valid[:, None] & (iota_P == entry[:, None]))
+            n_valid = jnp.sum(valid).astype(jnp.int32)
+            msgs = carry.msgs.at[0].add(2 * n_valid)
+            if probe_msgs:
+                msgs = msgs.at[1].add(probe_msgs * n_valid)
 
-                # -- commit the chunk (placements now known; FCFS rank
-                #    within the chunk preserved by _commit_rounds' occ)
-                commit = jnp.zeros((bsz,), bool).at[ic_eff].set(
-                    True, mode="drop")
-                j_full = jnp.zeros((bsz,), jnp.int32).at[ic_eff].set(
-                    j_c, mode="drop")
-                nt_c = node_type[j_c]
+            # ---- data-store protocol, once per block (cached-view policies)
+            if policy in ("dodoor", "one_plus_beta"):
+                delta = jnp.stack(
+                    [cores_t, mem_t, dest_t, jnp.ones_like(cores_t)], axis=1)
+                do_flush = (((idx // S) + 1) % fe_dyn == 0) & valid
+                # A delta survives into the carried accumulator iff its
+                # scheduler does not flush at or after it within this block
+                # (the flush at a task's own step clears the delta it just
+                # added).
+                flushed_after = jnp.any(
+                    (sched[None, :] == sched[:, None])
+                    & (tt[None, :] >= tt[:, None]) & do_flush[None, :], axis=1)
+                survives = valid & ~flushed_after
+                if retry:
+                    # A rejected placement queued nothing → reports no delta
+                    # (mirrors the sequential engine).
+                    survives = survives & ~(outs[8] > 0.5)
+                add = jnp.zeros_like(carry.pending).at[
+                    sched, jnp.clip(j, 0, n - 1)].add(
+                        delta * survives[:, None].astype(delta.dtype))
+                sched_flushed = jnp.zeros((S,), bool).at[
+                    jnp.where(do_flush, sched, S)].set(True, mode="drop")
+                pending = jnp.where(
+                    sched_flushed[:, None, None], 0.0, carry.pending) + add
+                carry = carry._replace(pending=pending)
+                msgs = msgs.at[3].add(jnp.sum(do_flush).astype(jnp.int32))
 
-                def scat(v):
-                    return jnp.zeros((bsz,), v.dtype).at[ic_eff].set(
-                        v, mode="drop")
-
-                c, outs = _commit_rounds(
-                    c, commit, now, j_full, scat(r_exec_t[ic, nt_c, 0]),
-                    scat(r_exec_t[ic, nt_c, 1]), scat(d_act_t[ic, nt_c]),
-                    scat(d_est_t[ic, nt_c]),
-                    jnp.zeros((bsz,), jnp.float32), dyn, win, cores_per,
-                    mem_unit, n, MU, outs0=outs, retry=retry)
-                j_acc = jnp.where(commit, j_full, j_acc)
-
-                # -- post-scheduling async probes: each task reads ground
-                #    truth as of *its own* decision point.  The chunk
-                #    committed first, so revert the rb slots written by
-                #    same-chunk commits at or after each task — reverse-
-                #    order (old, new) slot records telescope, exact even
-                #    when commits collide on a server or slot.
-                probes_c = probes[ic]                           # [S, rp]
-                rel_rows = c.rb_release[probes_c]               # [S, rp, R]
-                dur_rows = c.rb_dur[probes_c]
-                for kloc in reversed(range(S)):
-                    col = ic[kloc]
-                    jk = j_full[col]
-                    slot_k = outs[6, col].astype(jnp.int32)
-                    do = (commit[col] & (rows_s <= kloc)[:, None]
-                          & (probes_c == jk))
-                    rel_rows = rel_rows.at[:, :, slot_k].set(
-                        jnp.where(do, outs[4, col],
-                                  rel_rows[:, :, slot_k]))
-                    dur_rows = dur_rows.at[:, :, slot_k].set(
-                        jnp.where(do, outs[5, col],
-                                  dur_rows[:, :, slot_k]))
-                act = (rel_rows > now_c[:, None, None]).astype(jnp.float32)
-                prif = jnp.sum(act, axis=-1)                    # [S, rp]
-                pD = jnp.sum(dur_rows * act, axis=-1)
-
-                # -- pool insert (sequential r_probe order) + maintenance;
-                #    probes to down servers get no reply → no entry.
-                avail_p = jnp.take_along_axis(avail_c, probes_c, axis=1)
-                for ip in range(PP.r_probe):
-                    slot = jnp.argmin(jnp.where(pv, page, -jnp.inf), axis=1)
-                    one = (iota_P == slot[:, None]) & avail_p[:, ip:ip + 1]
-                    pserv = jnp.where(one, probes_c[:, ip:ip + 1], pserv)
-                    pr = jnp.where(one, prif[:, ip:ip + 1], pr)
-                    plat = jnp.where(one, pD[:, ip:ip + 1], plat)
-                    page = jnp.where(
-                        one, (now_c + jnp.float32(ip) * 1e-3)[:, None],
-                        page)
-                    pv = jnp.where(one, True, pv)
-                full = jnp.sum(pv, axis=1) >= P
-                worst = jnp.argmax(jnp.where(pv, pr, -jnp.inf), axis=1)
-                pv = pv & ~(full[:, None] & (iota_P == worst[:, None]))
-                c = c._replace(
-                    pool_server=c.pool_server.at[s_eff].set(pserv,
-                                                            mode="drop"),
-                    pool_rif=c.pool_rif.at[s_eff].set(pr, mode="drop"),
-                    pool_lat=c.pool_lat.at[s_eff].set(plat, mode="drop"),
-                    pool_age=c.pool_age.at[s_eff].set(page, mode="drop"),
-                    pool_valid=c.pool_valid.at[s_eff].set(pv, mode="drop"),
-                )
-                return (c, j_acc, outs)
-
-            state = (carry, jnp.zeros((bsz,), jnp.int32),
-                     jnp.zeros((orows, bsz), jnp.float32))
-            carry, j, outs = jax.lax.fori_loop(0, nchunks, chunk_body,
-                                               state)
-
-        o_start, o_finish, o_enq, o_sched = (outs[0], outs[1], outs[2],
-                                             outs[3])
-        if policy in ("pot", "prequal"):
-            nt_j = node_type[j]
-            cores_t = r_exec_t[tt, nt_j, 0]
-            mem_t = r_exec_t[tt, nt_j, 1]
-            dest_t = d_est_t[tt, nt_j]
-
-        n_valid = jnp.sum(valid).astype(jnp.int32)
-        msgs = carry.msgs.at[0].add(2 * n_valid)
-        if probe_msgs:
-            msgs = msgs.at[1].add(probe_msgs * n_valid)
-
-        # ---- data-store protocol, once per block (cached-view policies)
-        if policy in ("dodoor", "one_plus_beta"):
-            delta = jnp.stack(
-                [cores_t, mem_t, dest_t, jnp.ones_like(cores_t)], axis=1)
-            do_flush = (((idx // S) + 1) % fe_dyn == 0) & valid
-            # A delta survives into the carried accumulator iff its scheduler
-            # does not flush at or after it within this block (the flush at a
-            # task's own step clears the delta it just added).
-            flushed_after = jnp.any(
-                (sched[None, :] == sched[:, None])
-                & (tt[None, :] >= tt[:, None]) & do_flush[None, :], axis=1)
-            survives = valid & ~flushed_after
-            if retry:
-                # A rejected placement queued nothing → reports no delta
-                # (mirrors the sequential driver).
-                survives = survives & ~(outs[8] > 0.5)
-            add = jnp.zeros_like(carry.pending).at[
-                sched, jnp.clip(j, 0, n - 1)].add(
-                    delta * survives[:, None].astype(delta.dtype))
-            sched_flushed = jnp.zeros((S,), bool).at[
-                jnp.where(do_flush, sched, S)].set(True, mode="drop")
-            pending = jnp.where(
-                sched_flushed[:, None, None], 0.0, carry.pending) + add
-            carry = carry._replace(pending=pending)
-            msgs = msgs.at[3].add(jnp.sum(do_flush).astype(jnp.int32))
-
-            # Push fires at the block boundary — only a full block reaches
-            # the b-th decision (the padded tail never pushes), matching the
-            # sequential trigger (i+1) % b == 0 exactly.
-            now_push = now[-1]
-            do_push = valid[-1] & ~_suppress_push(win, dyn, now_push)
-            push_ord = ((idx[-1] + 1) // dyn_ints[0]) if cache_faulted \
-                else None
-            carry = jax.lax.cond(
-                do_push,
-                lambda c: _apply_push(c, now_push, dyn, win, S,
-                                      cache_faulted, push_ord),
-                lambda c: c, carry)
-            msgs = jnp.where(do_push, msgs.at[2].add(S), msgs)
-        carry = carry._replace(msgs=msgs)
+                # Push fires at the block boundary — only a full block
+                # reaches the b-th decision (the padded tail never pushes),
+                # matching the sequential trigger (i+1) % b == 0 exactly.
+                now_push = now[-1]
+                do_push = valid[-1] & ~_suppress_push(win, dyn, now_push)
+                push_ord = ((idx[-1] + 1) // dyn_ints[0]) if cache_faulted \
+                    else None
+                carry = jax.lax.cond(
+                    do_push,
+                    lambda c: _apply_push(c, now_push, dyn, win, S,
+                                          cache_faulted, push_ord),
+                    lambda c: c, carry)
+                msgs = jnp.where(do_push, msgs.at[2].add(S), msgs)
+            carry = carry._replace(msgs=msgs)
 
         out = (j, o_start, o_finish, o_enq, o_sched, cores_t, mem_t)
         if retry:

@@ -14,6 +14,8 @@ test process that runs this file loads the TPU library.  The persistent
 compilation cache is off around these compiles: an entry compiled for a
 described chip cannot be read back without one.
 """
+import re
+
 import pytest
 
 import jax
@@ -74,3 +76,23 @@ def test_serve_step_compiles_with_kernel(one_chip, n, b, masked):
     svc = DecisionService(cluster, cfg, use_kernel=True, capacity=b)
     compiled = svc.lower_step(one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_serve_step_stages_are_named(one_chip):
+    """The step's named scopes reach the compiled program's ``op_name``
+    metadata, which maps a device trace's ops to stages: the commit
+    rounds' ``while`` under ``commit``, and the kernel's custom-call,
+    named ``dodoor_fused_sparse``, under ``select``."""
+    cfg = EngineConfig(policy="dodoor", b=50, interpret=False)
+    svc = DecisionService(make_testbed(), cfg, use_kernel=True, capacity=50)
+    lines = svc.lower_step(one_chip).compile().as_text().splitlines()
+
+    def op_name(line):
+        return re.search(r'op_name="([^"]*)"', line).group(1)
+
+    whiles = [op_name(ln) for ln in lines if " while(" in ln]
+    assert whiles and all("/commit/" in n for n in whiles)
+    kernel, = [ln for ln in lines if "tpu_custom_call" in ln]
+    assert kernel.lstrip().startswith("%dodoor_fused_sparse.")
+    assert "/select/" in op_name(kernel)
+    assert op_name(kernel).endswith("/dodoor_fused_sparse/pallas_call")

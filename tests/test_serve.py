@@ -141,6 +141,75 @@ class TestStreamingSemantics:
         assert s1["view_L"].shape == (cluster.num_servers, 2)
 
 
+class TestTracing:
+    """The ``serve.*`` host spans on a profiler trace, and ``ring_wait``."""
+
+    PHASES = ("serve.ring_pop", "serve.upload", "serve.dispatch",
+              "serve.device_wait", "serve.readback", "serve.publish")
+
+    @pytest.fixture(scope="class")
+    def traced(self, cluster, wl, tmp_path_factory):
+        """A service driven through submit / drain / flush under the
+        profiler: the service and its ``serve.*`` events (name, block,
+        start, end) in start order."""
+        import glob
+        import os
+
+        import jax
+        from jax.profiler import ProfileData
+        log = str(tmp_path_factory.mktemp("serve_trace"))
+        m = wl.r_submit.shape[0]
+        svc = DecisionService(cluster, EngineConfig(policy="dodoor", b=25),
+                              capacity=m)
+        with jax.profiler.trace(log):
+            for lo in range(0, m, 40):
+                svc.submit_workload(wl, lo, min(lo + 40, m))
+                svc.drain()
+            svc.flush()
+        path, = glob.glob(os.path.join(log, "**", "*.xplane.pb"),
+                          recursive=True)
+        events = [(e.name, dict(e.stats).get("block"), e.start_ns, e.end_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("serve.")]
+        return svc, sorted(events, key=lambda e: e[2])
+
+    def test_one_parent_span_per_block(self, traced, wl):
+        svc, events = traced
+        parents = [e for e in events if e[0] in ("serve.step", "serve.flush")]
+        nb = -(-wl.r_submit.shape[0] // 25)
+        assert [e[1] for e in parents] == list(range(nb))
+        assert [e[0] for e in parents] == ["serve.step"] * (nb - 1) + [
+            "serve.flush"]                # 317 = 12·25 + a 17-task tail
+
+    def test_each_phase_once_in_order_with_the_block_id(self, traced):
+        _, events = traced
+        parents = [e for e in events if e[0] in ("serve.step", "serve.flush")]
+        children = [e for e in events if e[0] in self.PHASES]
+        assert len(children) == len(self.PHASES) * len(parents)
+        for name, k, lo, hi in parents:
+            inside = [c for c in children if lo <= c[2] and c[3] <= hi]
+            assert tuple(c[0] for c in inside) == self.PHASES, k
+            assert {c[1] for c in inside} == {k}
+
+    def test_submit_spans_name_the_first_task_block(self, traced, wl):
+        _, events = traced
+        submits = [e for e in events if e[0] == "serve.submit"]
+        m = wl.r_submit.shape[0]
+        assert [e[1] for e in submits] == [lo // 25
+                                          for lo in range(0, m, 40)]
+
+    def test_ring_wait_per_decision_within_its_latency(self, traced, wl):
+        svc, _ = traced
+        m = wl.r_submit.shape[0]
+        wait = svc.ring_wait.samples()
+        assert svc.ring_wait.count == m == svc.decision_latency.count
+        assert (wait >= 0).all()
+        assert (wait <= svc.decision_latency.samples()).all()
+        assert svc.latency_summary()["ring_wait"]["count"] == m
+
+
 class TestDonationAndCompiles:
     def test_zero_recompiles_after_warmup(self, cluster, wl):
         """Steady-state steps and the edge-padded flush tail reuse one
