@@ -54,9 +54,12 @@ class LatencyRecorder:
 
     def histogram(self, nbins: int = 24) -> dict:
         """Log-spaced buckets over the observed range: ``edges_ms`` has
-        ``nbins + 1`` entries, ``counts`` has ``nbins``.  Degenerate
-        ranges (all samples equal) widen to a ±10% band so the buckets
-        stay well-formed."""
+        ``nbins + 1`` entries, ``counts`` has ``nbins``, and every sample
+        is counted once.  The outer edges are the exact extremes (a
+        log-space round trip can land inside them and drop a sample);
+        samples below the 1 ns floor count in the first bucket.
+        Degenerate ranges (all samples equal) widen to a ±10% band so the
+        buckets stay well-formed."""
         s = self.samples()
         if not s.size:
             return {"edges_ms": [], "counts": []}
@@ -65,6 +68,7 @@ class LatencyRecorder:
         if hi <= lo:
             lo, hi = lo * 0.9, hi * 1.1
         edges = np.logspace(np.log10(lo), np.log10(hi), nbins + 1)
-        counts, _ = np.histogram(s, bins=edges)
+        edges[0], edges[-1] = lo, hi
+        counts, _ = np.histogram(np.clip(s, lo, hi), bins=edges)
         return {"edges_ms": [round(float(e), 6) for e in edges],
                 "counts": [int(c) for c in counts]}

@@ -33,7 +33,14 @@ block one ``serve.step`` (or ``serve.flush`` for the ragged tail) holding
 ``serve.ring_pop``, ``serve.upload``, ``serve.dispatch``,
 ``serve.device_wait``, ``serve.readback`` and ``serve.publish``, in that
 order.  They land on a profiler trace beside the device's operations and
-cost about a microsecond each when no profiler runs.
+cost about a microsecond each when no profiler runs.  Each block moves
+its data in one transfer each way: ``serve.upload`` stages the ids, the
+five planes and the validity mask in one int32 buffer, sends it, and
+splits it on the device (:func:`_unpack_block`, a program of its own);
+``serve.readback`` is one ``jax.device_get`` of the seven output planes
+and, when snapshots are published, the three post-step views, whose
+copies were queued behind the step when it was dispatched.  Both spans
+carry ``arrays`` and ``bytes`` tags: the arrays and bytes moved.
 """
 from __future__ import annotations
 
@@ -55,6 +62,22 @@ from .ring import ArrivalRing, ArrivalRows
 
 #: Host-side carry field order for checkpoints (must match _Carry).
 _CARRY_FIELDS = _Carry._fields
+
+
+@partial(jax.jit, static_argnames=("tt", "k"))
+def _unpack_block(buf, tt: int, k: int):
+    """The step's block operand from one staged int32 buffer
+    (:meth:`DecisionService._stage`): ids, the five planes bit-cast back
+    to float32, the ids again as task ids, and the validity mask."""
+    b = buf.shape[0]
+    widths = (1, k, tt * k, tt, tt, 1)
+    lo = np.cumsum((0,) + widths)
+    ids, r_submit, r_exec, d_est, d_act, submit_ms = (
+        buf[:, a:z] for a, z in zip(lo[:-1], lo[1:]))
+    f32 = partial(jax.lax.bitcast_convert_type, new_dtype=jnp.float32)
+    return (ids[:, 0], f32(r_submit), f32(r_exec).reshape(b, tt, k),
+            f32(d_est), f32(d_act), f32(submit_ms)[:, 0], ids[:, 0],
+            buf[:, -1] != 0)
 
 
 @partial(jax.jit, donate_argnums=(0,),
@@ -243,19 +266,24 @@ class DecisionService:
             self._ring_pad += pad
             return done + self._run_block(padded, k, block)
 
-    def _block(self, rows: ArrivalRows, valid_count: int) -> tuple:
-        """The step's block operand: global decision ids, the planes, and
-        the validity mask."""
+    def _stage(self, rows: ArrivalRows, valid_count: int) -> np.ndarray:
+        """Block ``rows`` as the one int32 buffer it is uploaded in, a row
+        per task: its global decision id, the five planes' float32 bits,
+        and whether it is valid (``valid_count`` leading rows are; the
+        rest edge-pad a ragged tail)."""
         b = self._b
-        ids = np.arange(self._next_idx, self._next_idx + b,
-                        dtype=np.int32)
-        ids_dev = jnp.asarray(ids)
-        mask = np.zeros((b,), bool)
-        mask[:valid_count] = True
-        return (ids_dev, jnp.asarray(rows.r_submit),
-                jnp.asarray(rows.r_exec), jnp.asarray(rows.d_est),
-                jnp.asarray(rows.d_act), jnp.asarray(rows.submit_ms),
-                ids_dev, jnp.asarray(mask))
+        ids = np.arange(self._next_idx, self._next_idx + b, dtype=np.int32)
+        valid = (np.arange(b) < valid_count).astype(np.int32)
+        cols = (ids[:, None], rows.r_submit, rows.r_exec.reshape(b, -1),
+                rows.d_est, rows.d_act, rows.submit_ms[:, None],
+                valid[:, None])
+        return np.concatenate([c.view(np.int32) for c in cols], axis=1)
+
+    def _upload(self, buf: np.ndarray) -> tuple:
+        """Send a staged block in one transfer and split it on the device
+        into the step's block operand."""
+        return _unpack_block(buf, tt=self.cluster.num_types,
+                             k=self._C.shape[1])
 
     def _step_operands(self, blk) -> tuple:
         return (self._carry, blk, self._C, self._node_type, self._mem_unit,
@@ -272,8 +300,8 @@ class DecisionService:
         a ``sharding`` the operands are abstract shapes placed there — a
         device of a described topology compiles for a chip that is not
         attached."""
-        operands = self._step_operands(
-            self._block(self._ring.zeros(self._b), self._b))
+        operands = self._step_operands(self._upload(
+            self._stage(self._ring.zeros(self._b), self._b)))
         if sharding is not None:
             operands = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
@@ -281,15 +309,26 @@ class DecisionService:
         return _serve_step.lower(*operands, **self._step_statics())
 
     def _run_block(self, rows: ArrivalRows, valid_count: int, k: int) -> int:
-        """Block ``k``'s round trip, one ``serve.*`` span per phase."""
+        """Block ``k``'s round trip, one ``serve.*`` span per phase: one
+        upload of the staged block, the step, and one readback of the
+        output planes and the published views, whose copies are queued
+        behind the step as soon as it is dispatched."""
         b = self._b
         t0 = time.perf_counter()
-        with TraceAnnotation("serve.upload", block=k):
-            blk = self._block(rows, valid_count)
+        with TraceAnnotation("serve.upload", block=k) as span:
+            buf = self._stage(rows, valid_count)
+            span.set_metadata(arrays=1, bytes=buf.nbytes)
+            blk = self._upload(buf)
         t_dispatch = time.perf_counter()
         with TraceAnnotation("serve.dispatch", block=k):
             self._carry, out = _serve_step(*self._step_operands(blk),
                                            **self._step_statics())
+            fetch = tuple(out[:7])
+            if self._publish:
+                fetch += (self._carry.view_L, self._carry.view_D,
+                          self._carry.view_rif)
+            for a in fetch:
+                a.copy_to_host_async()
         with TraceAnnotation("serve.device_wait", block=k):
             jax.block_until_ready(out)
         t1 = time.perf_counter()
@@ -297,9 +336,11 @@ class DecisionService:
         t_enq = rows.t_enq[:valid_count]
         self.ring_wait.record((t_dispatch - t_enq) * 1e3)
         self.decision_latency.record((t1 - t_enq) * 1e3)
-        with TraceAnnotation("serve.readback", block=k):
-            for acc, plane in zip(self._outs[:7], out):
-                acc.append(np.asarray(plane)[:valid_count])
+        with TraceAnnotation("serve.readback", block=k, arrays=len(fetch),
+                             bytes=sum(a.nbytes for a in fetch)):
+            got = jax.device_get(fetch)
+            for acc, plane in zip(self._outs[:7], got):
+                acc.append(plane[:valid_count])
         self._outs[7].append(rows.submit_ms[:valid_count])
         self._next_idx += b
         self._steps += 1
@@ -309,9 +350,7 @@ class DecisionService:
                 self._snaps[idx] = {
                     "step": self._steps,
                     "virtual_ms": float(rows.submit_ms[valid_count - 1]),
-                    "view_L": np.asarray(self._carry.view_L),
-                    "view_D": np.asarray(self._carry.view_D),
-                    "view_rif": np.asarray(self._carry.view_rif),
+                    "view_L": got[7], "view_D": got[8], "view_rif": got[9],
                 }
                 self._live = idx
         return valid_count

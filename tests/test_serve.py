@@ -140,6 +140,55 @@ class TestStreamingSemantics:
         assert s2["step"] == 2 and s1["step"] == 1
         assert s1["view_L"].shape == (cluster.num_servers, 2)
 
+    def test_snapshot_is_the_post_step_views_and_stays_put(self, cluster,
+                                                          wl):
+        """Each published snapshot holds, bit for bit, the views the step
+        left in the carry, and a later step leaves it as it was."""
+        svc = DecisionService(cluster, EngineConfig(policy="dodoor", b=25))
+        svc.submit_workload(wl, 0, 150)
+        fields = ("view_L", "view_D", "view_rif")
+        kept = []
+        for _ in range(6):
+            svc.step()
+            snap = svc.snapshot()
+            for f in fields:
+                assert np.array_equal(snap[f],
+                                      np.asarray(getattr(svc._carry, f)))
+            kept.append((snap, {f: snap[f].copy() for f in fields}))
+        assert any(not np.array_equal(kept[0][1][f], kept[-1][1][f])
+                   for f in fields)          # the views did move
+        for i, (snap, copy) in enumerate(kept, start=1):
+            assert snap["step"] == i
+            for f in fields:
+                assert np.array_equal(snap[f], copy[f]), (i, f)
+
+    def test_ragged_tail_uses_its_own_mask(self, cluster, wl, monkeypatch):
+        """Every block uploads its own validity mask: all true for a full
+        block, the 17 real tasks of flush()'s tail, which are then placed
+        as the offline engine places them; full blocks and the tail share
+        one unpack program."""
+        from repro.serve.service import _unpack_block
+        staged = []
+        stage = DecisionService._stage
+
+        def keep(svc, rows, valid_count):
+            staged.append(stage(svc, rows, valid_count))
+            return staged[-1]
+        monkeypatch.setattr(DecisionService, "_stage", keep)
+        cfg = EngineConfig(policy="dodoor", b=25)
+        off = simulate(wl, cluster, cfg, seed=0, mode="batched")
+        svc = DecisionService(cluster, cfg, seed=0, capacity=317)
+        svc.submit_workload(wl)
+        svc.step()
+        warm = _unpack_block._cache_size()
+        assert svc.drain() == 275
+        assert svc.flush() == 17
+        assert _unpack_block._cache_size() == warm
+        _assert_same(off, svc.result(), "ragged tail")
+        masks = [buf[:, -1].tolist() for buf in staged]
+        assert masks == [[1] * 25] * 12 + [[1] * 17 + [0] * 8]
+        ids = np.concatenate([buf[:, 0] for buf in staged])
+        assert ids.tolist() == list(range(325))
 
 class TestTracing:
     """The ``serve.*`` host spans on a profiler trace, and ``ring_wait``."""
@@ -148,9 +197,9 @@ class TestTracing:
               "serve.device_wait", "serve.readback", "serve.publish")
 
     @pytest.fixture(scope="class")
-    def traced(self, cluster, wl, tmp_path_factory):
+    def traced_stats(self, cluster, wl, tmp_path_factory):
         """A service driven through submit / drain / flush under the
-        profiler: the service and its ``serve.*`` events (name, block,
+        profiler: the service and its ``serve.*`` events (name, tags,
         start, end) in start order."""
         import glob
         import os
@@ -168,12 +217,19 @@ class TestTracing:
             svc.flush()
         path, = glob.glob(os.path.join(log, "**", "*.xplane.pb"),
                           recursive=True)
-        events = [(e.name, dict(e.stats).get("block"), e.start_ns, e.end_ns)
+        events = [(e.name, dict(e.stats), e.start_ns, e.end_ns)
                   for plane in ProfileData.from_file(path).planes
                   if plane.name.startswith("/host:")
                   for line in plane.lines for e in line.events
                   if e.name.startswith("serve.")]
         return svc, sorted(events, key=lambda e: e[2])
+
+    @pytest.fixture(scope="class")
+    def traced(self, traced_stats):
+        """The same events as (name, block, start, end)."""
+        svc, events = traced_stats
+        return svc, [(name, tags.get("block"), lo, hi)
+                     for name, tags, lo, hi in events]
 
     def test_one_parent_span_per_block(self, traced, wl):
         svc, events = traced
@@ -192,6 +248,29 @@ class TestTracing:
             inside = [c for c in children if lo <= c[2] and c[3] <= hi]
             assert tuple(c[0] for c in inside) == self.PHASES, k
             assert {c[1] for c in inside} == {k}
+
+    def test_one_transfer_each_way_tagged_with_what_it_moved(
+            self, traced_stats, cluster):
+        """``serve.upload`` sends the ids, the five planes and the mask as
+        one buffer; ``serve.readback`` fetches seven output planes and
+        three views in one call; each span says how many arrays and
+        bytes."""
+        svc, events = traced_stats
+        b, n, tt = 25, cluster.num_servers, cluster.num_types
+        planes = 4 * b * (1 + 2 + 2 * tt + tt + tt + 1)  # ids .. submit_ms
+        views = sum(np.asarray(getattr(svc._carry, f)).nbytes
+                    for f in ("view_L", "view_D", "view_rif"))
+        assert views == 4 * (2 * n + n + n)
+        uploads = [t for name, t, _, _ in events if name == "serve.upload"]
+        readbacks = [t for name, t, _, _ in events
+                     if name == "serve.readback"]
+        nb = len(uploads)
+        assert nb == len(readbacks) == -(-317 // b)
+        for t in uploads:       # one int32 buffer: the planes and a mask
+            assert (t["arrays"], t["bytes"]) == (1, planes + 4 * b)
+        for t in readbacks:
+            assert (t["arrays"], t["bytes"]) == (10, 7 * 4 * b + views)
+        assert [t["block"] for t in uploads] == list(range(nb))
 
     def test_submit_spans_name_the_first_task_block(self, traced, wl):
         _, events = traced
@@ -332,3 +411,22 @@ class TestRingAndLatencyUnits:
         assert sum(h["counts"]) == 100
         s = rec.summary()
         assert s["p99_ms"] <= s["max_ms"] == 100.0
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_histogram_counts_every_sample(self, seed):
+        """The outer edges hold the extremes exactly, so no sample falls
+        outside the buckets (a plain log-space grid can round its last
+        edge below the largest sample)."""
+        rng = np.random.default_rng(seed)
+        for size in (1, 2, 13, 13, 13, 40, 13, 97):
+            for _ in range(8):
+                rec = LatencyRecorder()
+                rec.record(rng.lognormal(0.0, 1.0, size=size))
+                h = rec.histogram()
+                assert sum(h["counts"]) == rec.count == size
+                s = rec.samples()
+                assert h["edges_ms"][0] <= round(float(s.min()), 6)
+                assert h["edges_ms"][-1] >= round(float(s.max()), 6)
+        rec = LatencyRecorder()
+        rec.record([0.0, 2.0, 3.0])        # below the 1 ns floor
+        assert sum(rec.histogram(nbins=4)["counts"]) == 3
