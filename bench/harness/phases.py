@@ -76,10 +76,11 @@ def scope_map(hlo_text: str) -> dict:
     return out
 
 
-def step_scopes(fleet, policy: dict) -> dict:
+def step_scopes(fleet, policy: dict, keywords: dict | None = None) -> dict:
     """The scope map of the served step as the cell runs it, compiled for
     the default device (the chip the trace came from): a throwaway service
-    on the cell's fleet and policy lowers its step for its block shapes.
+    on the cell's fleet, policy and ``service`` keywords lowers its step
+    for its block shapes.
 
     It is compiled afresh, past JAX's caches in memory and on disk: they
     key a program without its ``op_name`` metadata, so an entry made by a
@@ -90,7 +91,8 @@ def step_scopes(fleet, policy: dict) -> dict:
     from jax.experimental.compilation_cache import compilation_cache
 
     from harness import system
-    svc = system.service(fleet, policy, seed=0, capacity=int(policy["b"]))
+    svc = system.service(fleet, policy, seed=0, capacity=int(policy["b"]),
+                         keywords=keywords)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()

@@ -3,6 +3,14 @@
 The benchmark drives ``repro.serve.DecisionService`` through its public
 calls (``submit``, ``step``, ``flush``, ``result``) and reads one counter of
 it, ``compiles``.  This module is the only one that imports the program.
+
+How the service is built is the configuration's: its ``policy`` block sets
+every knob of ``EngineConfig``, and its optional ``service`` block gives
+keywords that go to ``DecisionService`` as they stand, wherever the
+harness builds one (the same keywords go to the configuration's plain
+reference).  A configuration that needs another keyword names it in its
+own file; no file here changes.  An unknown keyword fails at set-up with
+the program's own ``TypeError``.
 """
 from __future__ import annotations
 
@@ -32,11 +40,14 @@ def cluster(fleet) -> ClusterSpec:
                        type_names=fleet.type_names)
 
 
-def service(fleet, policy: dict, seed: int, capacity: int) -> DecisionService:
-    """A fresh service on the served path's defaults (kernel choice and
-    snapshot publishing as the program decides them)."""
+def service(fleet, policy: dict, seed: int, capacity: int,
+            keywords: dict | None = None) -> DecisionService:
+    """A fresh service with the configuration's ``service`` keywords;
+    without them, on the served path's defaults (kernel choice and snapshot
+    publishing as the program decides them)."""
     return DecisionService(cluster(fleet), engine_config(policy), seed=seed,
-                           capacity=max(int(capacity), int(policy["b"])))
+                           capacity=max(int(capacity), int(policy["b"])),
+                           **(keywords or {}))
 
 
 def submit(svc: DecisionService, tasks) -> int:
@@ -44,12 +55,13 @@ def submit(svc: DecisionService, tasks) -> int:
                       tasks.d_act, tasks.submit_ms)
 
 
-def warm_up(fleet, policy: dict, tasks) -> int:
+def warm_up(fleet, policy: dict, tasks, keywords: dict | None = None) -> int:
     """Compile and run every program the window will use on a throwaway
-    service: one full block through ``step`` and a ragged tail through
-    ``flush``.  Returns the service's compiled-program count."""
+    service built as the window's is: one full block through ``step`` and
+    a ragged tail through ``flush``.  Returns the service's
+    compiled-program count."""
     b = int(policy["b"])
-    svc = service(fleet, policy, seed=0, capacity=2 * b)
+    svc = service(fleet, policy, seed=0, capacity=2 * b, keywords=keywords)
     submit(svc, tasks.rows(0, b + b // 2))
     svc.step()
     svc.flush()

@@ -5,8 +5,13 @@ A traced run records the benchmark's own host spans (``bench.step``,
 around its calls into the service) and the device's operations on one
 clock.  This module reads the ``.xplane.pb`` with ``jax.profiler`` alone
 and offers the primitives the per-layer readers in ``bench/metrics/`` use:
-the device's operations, the step programs and kernel calls among them, the
+the devices' operations, the step programs and kernel calls among them, the
 host spans, busy time as a union of intervals, and the traced window.
+
+A cell on ``chips`` chips keeps the ``chips`` devices that ran the most
+programs, each one's events apart and all of them merged: ``busy`` is the
+time in which any of them worked, and ``busy_s`` the mean over the chips of
+each one's busy time.  With one chip both are that chip's.
 
 Names on the device are matched by substring, as read off a chip trace:
 ``DEVICE_OP_LINE`` is the line that holds one event per operation,
@@ -48,10 +53,11 @@ class Event(NamedTuple):
 
 
 class Trace(NamedTuple):
-    ops: list         # device operations (the busiest device)
-    programs: list    # device program executions (the busiest device)
+    ops: list         # device operations, every kept device's, in order
+    programs: list    # device program executions, every kept device's
     spans: list       # the benchmark's host spans
     lines: dict       # every (plane, line) name -> event count (diagnostic)
+    devices: tuple = ()   # per kept device, busiest first: (ops, programs)
 
 
 def find(log_dir: str) -> str | None:
@@ -60,9 +66,10 @@ def find(log_dir: str) -> str | None:
     return paths[-1] if paths else None
 
 
-def read(path: str) -> Trace:
-    """The trace's host spans, and the op and program events of the device
-    that ran the most programs (a cell on several chips drives one)."""
+def read(path: str, chips: int = 1) -> Trace:
+    """The trace's host spans, and the op and program events of the
+    ``chips`` devices that ran the most programs (a device the trace lacks
+    counts as one that ran nothing)."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     devices: dict = {}
@@ -82,12 +89,16 @@ def read(path: str) -> Trace:
                     dev[line.name] = evs
             elif plane.name.startswith("/host:"):
                 spans += [e for e in evs if e.name.startswith(SPAN_PREFIX)]
-    dev = max(devices.values(), key=lambda d: len(d.get(
-        DEVICE_PROGRAM_LINE, [])), default={})
+    kept = sorted(devices.values(), key=lambda d: -len(d.get(
+        DEVICE_PROGRAM_LINE, [])))[:chips]
+    kept += [{}] * (chips - len(kept))
     key = lambda e: e.start                                 # noqa: E731
-    return Trace(sorted(dev.get(DEVICE_OP_LINE, []), key=key),
-                 sorted(dev.get(DEVICE_PROGRAM_LINE, []), key=key),
-                 sorted(spans, key=key), lines)
+    per = tuple((sorted(d.get(DEVICE_OP_LINE, []), key=key),
+                 sorted(d.get(DEVICE_PROGRAM_LINE, []), key=key))
+                for d in kept)
+    return Trace(sorted((e for ops, _ in per for e in ops), key=key),
+                 sorted((e for _, pr in per for e in pr), key=key),
+                 sorted(spans, key=key), lines, per)
 
 
 def union(events) -> list:
@@ -118,7 +129,16 @@ class View:
         inside = lambda e: self.lo <= e.start and e.end <= self.hi  # noqa
         self.ops = [e for e in trace.ops if inside(e)]
         self.programs = [e for e in trace.programs if inside(e)]
-        self.busy = union(self.ops or self.programs)
+        # Each kept device's busy intervals (its ops, else its programs),
+        # and their union: the time in which any chip worked.
+        self.devices = [([e for e in ops if inside(e)],
+                         [e for e in programs if inside(e)])
+                        for ops, programs in trace.devices
+                        or ((trace.ops, trace.programs),)]
+        self.device_busy = [union(ops or programs)
+                            for ops, programs in self.devices]
+        self.busy = union([e for ops, programs in self.devices
+                           for e in ops or programs])
 
     @property
     def window_s(self) -> float:
@@ -126,7 +146,10 @@ class View:
 
     @property
     def busy_s(self) -> float:
-        return covered(self.busy, self.lo, self.hi) / 1e9
+        """Mean over the kept chips of each chip's busy time in the
+        window (s)."""
+        return sum(covered(iv, self.lo, self.hi) for iv in
+                   self.device_busy) / len(self.device_busy) / 1e9
 
     def spans(self, name: str) -> list:
         return [s for s in self.trace.spans if s.name == name]

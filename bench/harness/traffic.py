@@ -7,8 +7,9 @@ their parameters:
 ``loop``
     The loop ``bench/loops/<loop>.py`` that drives the service through the
     window and yields the cell's end-to-end values.  It exposes
-    ``prepare(rng, mix, fleet, sigma, seconds, b, rate)`` (the stream, drawn
-    from the seed, with the ring ``capacity`` it needs), ``run(svc, plan,
+    ``prepare(rng, mix, fleet, draw, seconds, b, rate)`` (the stream, drawn
+    from the seed by the configuration's task mix, ``draw(rng, m,
+    submit_ms)``, with the ring ``capacity`` it needs), ``run(svc, plan,
     seconds, b, submit, tick)`` (a :class:`Window`) and ``end_to_end(window,
     log)`` (the values of the end-to-end metrics it measures, by name).
 ``arrivals``
@@ -18,12 +19,13 @@ their parameters:
 ``load``
     The offered load as a share of the fleet's cores, for the
     configuration's task mix (tasks per second = load x cores / mean
-    core-seconds per task).
+    core-seconds per task, as :mod:`harness.tasks` has the mix give them).
 
-Any further key is the loop's or the process's own.  A new mix is a new
-data file; a new loop or arrival process is a new file beside the others,
-and no file that is there changes.  Every task stream is drawn from the
-run's seed alone.
+Any further key is the loop's or the process's own.  What the tasks are is
+the configuration's: its task mix (:mod:`harness.tasks`).  A new traffic
+mix is a new data file; a new loop, arrival process or task mix is a new
+file beside the others, and no file that is there changes.  Every task
+stream is drawn from the run's seed alone.
 """
 from __future__ import annotations
 
@@ -33,8 +35,8 @@ import time
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from . import functionbench as fb
 from . import named
+from .tasks import Tasks
 
 CHUNK = 1 << 15     # tasks drawn per generator call of an endless stream
 
@@ -65,9 +67,10 @@ def load(bench_dir: str, name: str) -> Mix:
     return Mix(params, loop, arrivals)
 
 
-def rate_per_s(mix: Mix, fleet) -> float:
-    """Tasks per second: the mix's load on this fleet."""
-    return fb.rate_at_load(fleet, float(mix["load"]))
+def rate_per_s(mix: Mix, tasks) -> float:
+    """Tasks per second: the mix's load on the fleet, for the
+    configuration's task mix ``tasks`` (a :class:`harness.tasks.TaskMix`)."""
+    return tasks.rate_at_load(float(mix["load"]))
 
 
 def schedule(gaps, rng: np.random.Generator, rate: float,
@@ -88,10 +91,8 @@ class Stream:
     follow ``gaps``; the same seed gives the same stream however much of it
     a run consumes."""
 
-    def __init__(self, rng: np.random.Generator, gaps, type_names,
-                 sigma: float, prefill: int):
-        self._rng, self._gaps, self._types = rng, gaps, type_names
-        self._sigma = sigma
+    def __init__(self, rng: np.random.Generator, gaps, draw, prefill: int):
+        self._rng, self._gaps, self._draw = rng, gaps, draw
         self._t_ms = 0.0
         self._parts: list = []
         self._have = 0
@@ -102,8 +103,7 @@ class Stream:
     def _grow(self) -> None:
         submit = self._t_ms + np.cumsum(self._gaps(self._rng, CHUNK) * 1e3)
         self._t_ms = float(submit[-1])
-        self._parts.append(fb.draw(self._rng, self._types, CHUNK,
-                                   self._sigma, submit))
+        self._parts.append(self._draw(self._rng, CHUNK, submit))
         self._have += CHUNK
 
     def take(self, k: int):
@@ -118,7 +118,7 @@ class Stream:
         """Rows ``[lo, hi)``, joining only the chunks they span."""
         c0, c1 = lo // CHUNK, (hi - 1) // CHUNK
         parts = self._parts[c0:c1 + 1]
-        joined = parts[0] if len(parts) == 1 else fb.Tasks(
+        joined = parts[0] if len(parts) == 1 else Tasks(
             *(np.concatenate(cols) for cols in zip(*parts)))
         return joined.rows(lo - c0 * CHUNK, hi - c0 * CHUNK)
 

@@ -22,9 +22,9 @@ class Plan:
         self.capacity = 2 * b
 
 
-def prepare(rng, mix, fleet, sigma: float, seconds: float, b: int,
+def prepare(rng, mix, fleet, draw, seconds: float, b: int,
             rate: float) -> Plan:
-    return Plan(traffic.Stream(rng, mix.gaps(rate), fleet.type_names, sigma,
+    return Plan(traffic.Stream(rng, mix.gaps(rate), draw,
                                int(mix["prefill_per_s"] * seconds)), b)
 
 

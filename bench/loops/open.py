@@ -13,9 +13,9 @@ import time
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from harness import functionbench as fb
 from harness import traffic
 from harness.latency import Recorder
+from harness.tasks import Tasks
 
 
 class Plan:
@@ -24,11 +24,10 @@ class Plan:
         self.capacity = len(due_s) + b
 
 
-def prepare(rng, mix, fleet, sigma: float, seconds: float, b: int,
+def prepare(rng, mix, fleet, draw, seconds: float, b: int,
             rate: float) -> Plan:
     due = traffic.schedule(mix.gaps(rate), rng, rate, seconds)
-    return Plan(fb.draw(rng, fleet.type_names, len(due), sigma, due * 1e3),
-                due, b)
+    return Plan(draw(rng, len(due), due * 1e3), due, b)
 
 
 def run(svc, plan: Plan, seconds: float, b: int, submit,
@@ -93,7 +92,7 @@ def run(svc, plan: Plan, seconds: float, b: int, submit,
         place(svc.available, svc.flush)
     w.compiles = (w.compiles[0], svc.compiles)
     w.t0, w.placed = t0, placed
-    w.tasks = fb.Tasks(*(a[accepted] for a in tasks))
+    w.tasks = Tasks(*(a[accepted] for a in tasks))
     w.due = t0 + due_s[accepted]
     w.dispatch, w.done = dispatch[:placed], done[:placed]
     return w

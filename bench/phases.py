@@ -65,12 +65,12 @@ def main(argv=None) -> int:
     if args.map:
         smap = named.json_file(args.map)
     else:
-        smap = phases.step_scopes(fleet, policy)
+        smap = phases.step_scopes(fleet, policy, cfg.get("service"))
         if args.map_out:
             with open(args.map_out, "w") as f:
                 json.dump(smap, f, sort_keys=True)
-    view = tr.View(tr.read(args.trace))
-    ctx = run.Context(view, None, fleet, policy, None)
+    view = tr.View(tr.read(args.trace, int(cell["chips"])))
+    ctx = run.Context(view, None, fleet, policy, None, cfg)
     readers = {m["name"]: run.reader(run.BENCH, m["name"]) for m in layer
                if m["name"].split(".")[0] in ("host_gap_ms", "commit_ms",
                                               "kernel_ms",

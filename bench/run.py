@@ -6,13 +6,17 @@
 
 Everything is found by name from ``BENCHMARK.json`` at the checkout's root:
 the cell names a configuration (``bench/configs/<config>.json``, whose
-``reference`` names ``bench/reference/<reference>.py``) and a traffic mix
-(``bench/traffic/<traffic>.json``, which names its loop in ``bench/loops/``
-and its arrival process in ``bench/arrivals/``); each per-layer metric is
-a reader in ``bench/metrics/<metric>.py``, or in the file of its base name
-(``host_gap_ms.py`` for ``host_gap_ms.open``).  Adding a configuration, a
-mix, a loop, an arrival process or a metric adds files and entries, and
-edits none.
+``reference`` names ``bench/reference/<reference>.py``, whose ``tasks.mix``
+names its task mix in ``bench/tasks/``, and whose optional ``service``
+block gives the keywords that build the service and go to the reference)
+and a traffic mix (``bench/traffic/<traffic>.json``, which names its loop
+in ``bench/loops/`` and its arrival process in ``bench/arrivals/``); each
+per-layer metric is a reader in ``bench/metrics/<metric>.py``, or in the
+file of its base name (``host_gap_ms.py`` for ``host_gap_ms.open``).  The
+cell's ``chips`` are all counted: the traced busy time is the mean over
+them, the memory peak the largest.  Adding a configuration, a task mix, a
+service keyword, a traffic mix, a loop, an arrival process, a metric or a
+cell on several chips adds files and entries, and edits none.
 
 One run: synthesize the fleet and the task stream from ``--seed``, warm up
 the served path (set-up), drive ``DecisionService`` for ``--seconds``
@@ -58,8 +62,8 @@ import numpy as np  # noqa: E402
 T_IMPORTED = time.perf_counter()
 
 from harness import fleet as fleets  # noqa: E402
-from harness import functionbench as fb  # noqa: E402
 from harness import named, roofline, stalls, system  # noqa: E402
+from harness import tasks as task_mixes  # noqa: E402
 from harness import trace as tr, traffic  # noqa: E402
 
 TRACE_SECONDS = 3.0     # the traced sub-window: the window's last seconds
@@ -121,9 +125,16 @@ def check_device(chips: int, allow_cpu: bool) -> dict:
             "count": chips}
 
 
-def peak_bytes() -> int | None:
-    stats = jax.devices()[0].memory_stats()
-    return None if stats is None else int(stats.get("peak_bytes_in_use", 0))
+def peak_bytes(devices) -> tuple[int | None, list]:
+    """The largest ``peak_bytes_in_use`` over ``devices``, and each
+    device's (``None`` where the backend reports no memory)."""
+    each = []
+    for d in devices:
+        stats = d.memory_stats()
+        each.append(None if stats is None
+                     else int(stats.get("peak_bytes_in_use", 0)))
+    known = [v for v in each if v is not None]
+    return (max(known) if known else None), each
 
 
 class Profiler:
@@ -186,20 +197,22 @@ def drive(spec: dict, cell_name: str, seed: int, seconds: float,
                                             cfg["reference"] + ".py"))
     r.readers = {m["name"]: reader(bench_dir, m["name"])
                  for m in r.layer} if trace else {}
+    r.config = cfg
     r.fleet = fleet = fleets.build(cfg["fleet"])
     r.policy = policy = cfg["policy"]
+    r.keywords = cfg.get("service", {})
     b = int(policy["b"])
-    sigma = float(cfg["tasks"]["duration_noise_sigma"])
+    tasks = task_mixes.load(bench_dir, cfg["tasks"], fleet)
     r.svc_seed = seed % (1 << SEED_BITS)
-    rate = rate or traffic.rate_per_s(r.mix, fleet)
+    rate = rate or traffic.rate_per_s(r.mix, tasks)
     plan = r.mix.loop.prepare(np.random.default_rng(seed), r.mix, fleet,
-                              sigma, seconds, b, rate)
-    warm = fb.draw(np.random.default_rng(0), fleet.type_names, 2 * b,
-                   sigma, np.arange(2 * b, dtype=np.float32))
+                              tasks.draw, seconds, b, rate)
+    warm = tasks.draw(np.random.default_rng(0), 2 * b,
+                      np.arange(2 * b, dtype=np.float32))
     t_drawn = time.perf_counter()
-    system.warm_up(fleet, policy, warm)
+    system.warm_up(fleet, policy, warm, r.keywords)
     svc = stalls.Watched(system.service(fleet, policy, r.svc_seed,
-                                        plan.capacity))
+                                        plan.capacity, r.keywords))
     # Everything set-up made is kept out of the collector's later passes, so
     # that a full collection in the window scans only what the window made.
     gc.collect()
@@ -236,7 +249,9 @@ def drive(spec: dict, cell_name: str, seed: int, seconds: float,
             f"at {st[worst, 0] - w.t0:.3f} s into the window", flush=True)
     for line in svc.report(w.t0):
         log(line, flush=True)
-    r.device["memory_peak_bytes"] = peak_bytes()
+    peak, each = peak_bytes(jax.devices()[:r.device["count"]])
+    r.device["memory_peak_bytes"] = peak
+    r.device["memory_peak_bytes_by_device"] = each
     r.got = system.placements(svc.svc)
     svc.close()
     del svc
@@ -245,9 +260,10 @@ def drive(spec: dict, cell_name: str, seed: int, seconds: float,
 
 
 def reference_run(r: Drive, dtype=None, log=print) -> dict:
-    """The plain reference over every task the window submitted."""
+    """The plain reference over every task the window submitted, given
+    the configuration's ``service`` keywords as the service was."""
     t0 = time.perf_counter()
-    kw = {} if dtype is None else {"dtype": dtype}
+    kw = dict(r.keywords) if dtype is None else dict(r.keywords, dtype=dtype)
     want = r.reference.simulate(r.fleet, r.policy, r.window.tasks,
                                 r.svc_seed, **kw)
     log(f"reference: {len(r.window.tasks)} decisions in "
@@ -282,10 +298,10 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
             os.makedirs(keep_trace, exist_ok=True)
             shutil.copy(path, os.path.join(keep_trace, cell_name
                                            + ".xplane.pb"))
-        view = tr.View(tr.read(path)) if path else None
+        view = tr.View(tr.read(path, r.device["count"])) if path else None
         shutil.rmtree(r.log_dir, ignore_errors=True)
         if view is not None:
-            ctx = Context(view, w, r.fleet, r.policy, r.peaks)
+            ctx = Context(view, w, r.fleet, r.policy, r.peaks, r.config)
             units = {m["name"]: m["unit"] for m in r.layer}
             for name, mod in r.readers.items():
                 v = mod.read(ctx)
@@ -306,11 +322,16 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float,
 
 class Context:
     """What a per-layer reader may read: the traced window's view, the
-    window's host-side record, the fleet, the policy and the peaks."""
+    window's host-side record, the fleet, the policy, the peaks and the
+    whole configuration (``service`` is its service keywords)."""
 
-    def __init__(self, view, window, fleet, policy, peaks):
+    def __init__(self, view, window, fleet, policy, peaks, config=None):
         self.view, self.window, self.fleet = view, window, fleet
-        self.policy, self.peaks = policy, peaks
+        self.policy, self.peaks, self.config = policy, peaks, config
+
+    @property
+    def service(self) -> dict:
+        return (self.config or {}).get("service", {})
 
 
 def main(argv=None) -> int:

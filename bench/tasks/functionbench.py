@@ -4,13 +4,14 @@ A copy of the program's generator arithmetic, kept with the benchmark so
 that the yardstick does not move when the program does.  Eight serverless
 functions, drawn uniformly; each has per-node-type cores, memory (MB) and a
 profiled duration (ms).  The scheduler sees the profile; the task runs for
-the profile times a lognormal(0, sigma) factor.
+the profile times a lognormal(0, sigma) factor, sigma being the
+configuration's ``tasks.duration_noise_sigma``.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
-
 import numpy as np
+
+from harness.tasks import Tasks
 
 # Table 4: {task: {node_type: (cores, mem_mb, time_ms)}}
 TABLE4 = {
@@ -34,21 +35,6 @@ TABLE4 = {
 TASKS = tuple(TABLE4)
 
 
-class Tasks(NamedTuple):
-    """A stream of tasks, as the served path takes them."""
-    r_submit: np.ndarray   # [m, 2] declared demand (mean over node types)
-    r_exec: np.ndarray     # [m, T, 2] per-node-type cores and MB
-    d_est: np.ndarray      # [m, T] profiled duration per node type (ms)
-    d_act: np.ndarray      # [m, T] actual duration per node type (ms)
-    submit_ms: np.ndarray  # [m] virtual arrival time (ms)
-
-    def __len__(self) -> int:
-        return int(self.submit_ms.shape[0])
-
-    def rows(self, lo: int, hi: int) -> "Tasks":
-        return Tasks(*(a[lo:hi] for a in self))
-
-
 def profiles(type_names) -> tuple[np.ndarray, np.ndarray]:
     """(res [tasks, T, 2], dur [tasks, T]) with node types in the fleet's
     order."""
@@ -62,7 +48,7 @@ def profiles(type_names) -> tuple[np.ndarray, np.ndarray]:
     return res, dur
 
 
-def core_seconds_per_task(fleet) -> float:
+def core_seconds_per_task(fleet, params: dict) -> float:
     """Mean core-seconds a task of the uniform mix holds, over the fleet:
     each node type's mean of cores x seconds, weighted by that type's share
     of the fleet's cores."""
@@ -73,15 +59,11 @@ def core_seconds_per_task(fleet) -> float:
     return float(per_type @ share)
 
 
-def rate_at_load(fleet, load: float) -> float:
-    """Tasks per second that hold ``load`` of the fleet's cores busy."""
-    return load * fleet.cores / core_seconds_per_task(fleet)
-
-
-def draw(rng: np.random.Generator, type_names, m: int, sigma: float,
+def draw(rng: np.random.Generator, fleet, m: int, params: dict,
          submit_ms: np.ndarray) -> Tasks:
     """``m`` tasks of the uniform mix with the given virtual arrivals."""
-    res, dur = profiles(type_names)
+    res, dur = profiles(fleet.type_names)
+    sigma = float(params["duration_noise_sigma"])
     kind = rng.integers(0, len(TASKS), size=m)
     noise = np.exp(rng.normal(0.0, sigma, size=(m, 1))).astype(np.float32)
     d_est = dur[kind]

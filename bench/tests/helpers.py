@@ -5,6 +5,8 @@ import json
 import os
 
 from conftest import BENCH, ROOT
+from harness import fleet as fleets
+from harness import named, tasks
 
 
 def spec() -> dict:
@@ -17,6 +19,16 @@ def config(name: str) -> dict:
         return json.load(f)
 
 
+def task_mix(name: str) -> tasks.TaskMix:
+    """The task mix of configuration ``name`` on its own fleet."""
+    cfg = config(name)
+    return tasks.load(BENCH, cfg["tasks"], fleets.build(cfg["fleet"]))
+
+
+def functionbench():
+    return named.module(os.path.join(BENCH, "tasks", "functionbench.py"))
+
+
 def tiny_config() -> dict:
     cfg = copy.deepcopy(config("testbed-fb"))
     cfg["name"] = "tiny-fb"
@@ -25,13 +37,13 @@ def tiny_config() -> dict:
     return cfg
 
 
-def tiny_spec(traffic: str = "drain") -> dict:
+def tiny_spec(traffic: str = "drain", config: str = "tiny-fb") -> dict:
     s = spec()
-    s["configs"].append({"name": "tiny-fb", "source": "test",
-                         "file": "bench/configs/tiny-fb.json",
+    s["configs"].append({"name": config, "source": "test",
+                         "file": f"bench/configs/{config}.json",
                          "reduced": [], "why": "test"})
-    s["workloads"].append({"name": "tiny-fb." + traffic,
-                           "config": "tiny-fb", "traffic": traffic,
+    s["workloads"].append({"name": config + "." + traffic,
+                           "config": config, "traffic": traffic,
                            "chips": 1, "why": "test"})
     for m in s["end_to_end"]:
         m.pop("workloads", None)

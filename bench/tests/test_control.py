@@ -6,8 +6,7 @@ import numpy as np
 
 import run
 from harness import fleet as fleets
-from harness import functionbench as fb
-from helpers import config
+from helpers import config, task_mix
 from reference import dodoor
 
 
@@ -16,8 +15,9 @@ def test_bf16_scores_fail_the_comparison():
     fl = fleets.build(cfg["fleet"])
     rng = np.random.default_rng(2**31 + 11)
     m = 20_000
-    due_ms = np.cumsum(rng.exponential(1000 / fb.rate_at_load(fl, 0.8), m))
-    tasks = fb.draw(rng, fl.type_names, m, 0.1, due_ms)
+    mix = task_mix("testbed-fb")
+    due_ms = np.cumsum(rng.exponential(1000 / mix.rate_at_load(0.8), m))
+    tasks = mix.draw(rng, m, due_ms)
     want = dodoor.simulate(fl, cfg["policy"], tasks, 11)
     control = dodoor.simulate(fl, cfg["policy"], tasks, 11,
                               dtype=jnp.bfloat16)

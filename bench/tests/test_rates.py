@@ -2,10 +2,9 @@
 import pytest
 
 from harness import fleet as fleets
-from harness import functionbench as fb
 from conftest import BENCH
 from harness import traffic
-from helpers import config
+from helpers import config, functionbench, task_mix
 
 
 @pytest.mark.parametrize("name,servers,cores,rate", [
@@ -16,8 +15,9 @@ def test_rate_at_80pct_of_cores(name, servers, cores, rate):
     cfg = config(name)
     fl = fleets.build(cfg["fleet"])
     assert fl.n == servers and fl.cores == cores
-    assert fb.core_seconds_per_task(fl) == pytest.approx(12.6929, abs=1e-3)
-    assert fb.rate_at_load(fl, 0.8) == pytest.approx(rate, rel=1e-4)
+    assert functionbench().core_seconds_per_task(
+        fl, cfg["tasks"]) == pytest.approx(12.6929, abs=1e-3)
+    assert task_mix(name).rate_at_load(0.8) == pytest.approx(rate, rel=1e-4)
     assert cfg["derived"]["tasks_per_s_at_load_0.8"] == pytest.approx(
         rate, rel=1e-3)
     assert cfg["derived"]["cores"] == cores
@@ -25,7 +25,7 @@ def test_rate_at_80pct_of_cores(name, servers, cores, rate):
 
 def test_per_type_means_match_table4():
     fl = fleets.build(config("testbed-fb")["fleet"])
-    res, dur = fb.profiles(fl.type_names)
+    res, dur = functionbench().profiles(fl.type_names)
     per_type = (res[:, :, 0] * dur / 1e3).mean(axis=0)
     assert per_type == pytest.approx([12.9, 8.97, 10.8, 15.65], abs=0.01)
 
@@ -44,7 +44,7 @@ def test_fleets_equal_the_programs():
 @pytest.mark.parametrize("mix,cfg,rate", [("open", "cell10k-fb", 8407.9),
                                           ("drain", "testbed-fb", 84.08)])
 def test_committed_mixes_rate(mix, cfg, rate):
-    fl = fleets.build(config(cfg)["fleet"])
     m = traffic.load(BENCH, mix)
-    assert traffic.rate_per_s(m, fl) == pytest.approx(rate, rel=1e-4)
+    assert traffic.rate_per_s(m, task_mix(cfg)) == pytest.approx(rate,
+                                                                  rel=1e-4)
     assert callable(m.loop.run) and callable(m.loop.end_to_end)

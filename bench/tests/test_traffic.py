@@ -7,8 +7,8 @@ import numpy as np
 import pytest
 
 from conftest import BENCH
-from harness import functionbench as fb
 from harness import named, traffic
+from helpers import task_mix
 
 OPEN = named.module(os.path.join(BENCH, "loops", "open.py"))
 
@@ -38,8 +38,7 @@ def test_due_times_are_a_poisson_schedule_inside_the_window():
 def test_backlog_stream_is_the_same_however_it_is_taken():
     def stream():
         return traffic.Stream(np.random.default_rng(7), poisson(84.0),
-                              ("m510", "xl170", "c6525-25g", "c6620"), 0.1,
-                              10)
+                              task_mix("testbed-fb").draw, 10)
     a, b = stream(), stream()
     a.take(traffic.CHUNK + 100)
     for k in (7, traffic.CHUNK - 7, 100):
@@ -82,8 +81,8 @@ def test_latency_runs_from_the_due_time_through_a_stall():
     b, rate, seconds = 10, 1000.0, 1.0
     due = traffic.schedule(poisson(rate), np.random.default_rng(3), rate,
                            seconds)
-    tasks = fb.draw(np.random.default_rng(4), ("m510", "xl170", "c6525-25g",
-                    "c6620"), len(due), 0.1, due * 1e3)
+    tasks = task_mix("testbed-fb").draw(np.random.default_rng(4), len(due),
+                                        due * 1e3)
     svc = StallingService(b, 0.001, 0.5, 0.2)
 
     def submit(s, rows):
