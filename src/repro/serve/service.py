@@ -41,6 +41,21 @@ splits it on the device (:func:`_unpack_block`, a program of its own);
 and, when snapshots are published, the three post-step views, whose
 copies were queued behind the step when it was dispatched.  Both spans
 carry ``arrays`` and ``bytes`` tags: the arrays and bytes moved.
+
+Mini-clusters (§4.2): ``DecisionService(..., mini_clusters=k)`` splits the
+fleet into ``k`` interleaved parts (server ``j`` to part ``j mod k``,
+:func:`repro.sim.hierarchy.split_cluster`), each with its own schedulers,
+data store, batch counter and PRNG key ``PRNGKey(seed + c)``, and deals
+each ``b``-task block round-robin (decision ``i`` to part ``i mod k``), so
+part ``c`` steps ``b/k`` of its own decisions at a time.  One program
+(:func:`_serve_step_parts`) runs every part, each part's state on its own
+device where there are enough; the results are merged back into
+submission order with global server ids and the parts' message ledgers
+summed, bit-exact with ``simulate_hierarchical(..., mode="batched",
+b=b // k)``.  A block still moves in one transfer each way:
+``serve.upload`` holds ``serve.deal`` (the rows dealt into one
+``[k, b/k, W]`` buffer) and ``serve.readback`` holds ``serve.merge``, all
+four tagged ``parts=k``.
 """
 from __future__ import annotations
 
@@ -51,17 +66,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..sim.cluster import ClusterSpec
 from ..sim.engine import (Dynamics, EngineConfig, SimResult, _Carry,
                           _cluster_arrays, _init_carry, _lower_dynamics,
                           _make_block_step, _make_dyn, _make_dyn_ints,
                           _static_cfg, _validate_config, resolve_use_kernel)
+from ..sim.hierarchy import split_cluster
 from .latency import LatencyRecorder
 from .ring import ArrivalRing, ArrivalRows
 
 #: Host-side carry field order for checkpoints (must match _Carry).
 _CARRY_FIELDS = _Carry._fields
+
+#: Operands stacked on a leading axis of mini-clusters, sharded over the
+#: mesh's one axis; and operands every mini-cluster shares.
+_STACKED = PartitionSpec("parts")
+_SHARED = PartitionSpec()
+#: The step's operands (:meth:`DecisionService._step_operands`) under
+#: mini-clusters: all stacked but ``dyn_vec``, ``dyn_ints`` and ``win``.
+_STEP_SPECS = (_STACKED,) * 6 + (_SHARED,) * 3 + (_STACKED,)
 
 
 @partial(jax.jit, static_argnames=("tt", "k"))
@@ -78,6 +103,56 @@ def _unpack_block(buf, tt: int, k: int):
     return (ids[:, 0], f32(r_submit), f32(r_exec).reshape(b, tt, k),
             f32(d_est), f32(d_act), f32(submit_ms)[:, 0], ids[:, 0],
             buf[:, -1] != 0)
+
+
+def _pack(ids, rows: ArrivalRows, valid) -> np.ndarray:
+    """Rows as the int32 buffer a block is uploaded in, one row per task:
+    its decision id, the five planes' float32 bits and its validity.
+    ``ids`` and ``valid`` shape the leading axes."""
+    cols = (ids[..., None], rows.r_submit,
+            rows.r_exec.reshape(ids.shape + (-1,)), rows.d_est, rows.d_act,
+            rows.submit_ms[..., None], valid.astype(np.int32)[..., None])
+    return np.concatenate([c.view(np.int32) for c in cols], axis=-1)
+
+
+def _over_parts(fn, mesh: Mesh, specs: tuple):
+    """``fn`` applied to every mini-cluster of operands stacked on a leading
+    axis of parts, that axis sharded over ``mesh``.  ``specs`` marks each
+    operand ``_STACKED`` or ``_SHARED`` (the same for every part).  A device
+    that holds one part runs ``fn`` on it alone, so the program on it is
+    the one-part program; a device that holds several maps ``fn`` over
+    them with ``vmap``."""
+    stacked = tuple(s == _STACKED for s in specs)
+
+    def local(*args):
+        held = next(jax.tree.leaves(a)[0].shape[0]
+                    for a, s in zip(args, stacked) if s)
+        if held > 1:
+            return jax.vmap(fn, in_axes=tuple(0 if s else None
+                                              for s in stacked))(*args)
+        one = [jax.tree.map(lambda x: x[0], a) if s else a
+               for a, s in zip(args, stacked)]
+        return jax.tree.map(lambda x: x[None], fn(*one))
+
+    return jax.shard_map(local, mesh=mesh, in_specs=specs,
+                         out_specs=_STACKED, check_vma=False)
+
+
+@partial(jax.jit, static_argnames=("tt", "k", "mesh"))
+def _unpack_parts(buf, tt: int, k: int, mesh: Mesh):
+    """:func:`_unpack_block` on every part of a dealt ``[parts, b/parts,
+    W]`` buffer, on the devices that hold the parts."""
+    return _over_parts(partial(_unpack_block, tt=tt, k=k), mesh,
+                       (_STACKED,))(buf)
+
+
+@partial(jax.jit, static_argnames=("cfg", "n", "mesh"))
+def _init_carry_parts(cores_per, cfg: EngineConfig, n: int,
+                      mesh: Mesh) -> _Carry:
+    """Every part's t=0 carry, stacked, each made on its part's device and
+    laid out as the step returns it."""
+    return _over_parts(partial(_init_carry, cfg, n, faulted=False), mesh,
+                       (_STACKED,))(cores_per)
 
 
 @partial(jax.jit, donate_argnums=(0,),
@@ -99,6 +174,91 @@ def _serve_step(carry, blk, C, node_type, mem_unit, cores_per, dyn_vec,
     return step(carry, blk)
 
 
+@partial(jax.jit, donate_argnums=(0,),
+         static_argnames=("cfg", "n", "use_kernel", "kernel_masked",
+                          "cache_faulted", "mesh"))
+def _serve_step_parts(carry, blk, C, node_type, mem_unit, cores_per, dyn_vec,
+                      dyn_ints, win, base_key, cfg: EngineConfig, n: int,
+                      use_kernel: bool, kernel_masked: bool,
+                      cache_faulted: bool, mesh: Mesh):
+    """:func:`_serve_step` for every mini-cluster in one program, with the
+    stacked carry donated: the operands but ``dyn_vec``, ``dyn_ints`` and
+    ``win`` carry a leading axis of parts sharded over ``mesh``, and ``n``
+    and ``cfg.b`` are one part's."""
+    def part(carry, blk, C, node_type, mem_unit, cores_per, dyn_vec,
+             dyn_ints, win, base_key):
+        step = _make_block_step(C, node_type, mem_unit, cores_per, dyn_vec,
+                                dyn_ints, win, base_key, cfg, n, use_kernel,
+                                kernel_masked, cache_faulted, False)
+        return step(carry, blk)
+
+    return _over_parts(part, mesh, _STEP_SPECS)(
+        carry, blk, C, node_type, mem_unit, cores_per, dyn_vec, dyn_ints,
+        win, base_key)
+
+
+def _parts_mesh(k: int) -> Mesh:
+    """A 1-D mesh over ``jax.devices()[:d]``, ``d`` the largest count of
+    devices that divides ``k``."""
+    have = len(jax.devices())
+    d = max(x for x in range(1, min(k, have) + 1) if k % x == 0)
+    return Mesh(np.array(jax.devices()[:d]), ("parts",))
+
+
+class _MiniClusters:
+    """The §4.2 split of one service's fleet and stream: ``count`` parts,
+    server ``j`` in part ``j mod count`` and decision ``i`` in part
+    ``i mod count``, each part's operands stacked on a leading axis that
+    ``mesh`` shards."""
+
+    def __init__(self, cluster: ClusterSpec, count: int, b: int,
+                 mesh: Mesh):
+        self.count, self.block = count, b // count
+        self.parts = split_cluster(cluster, count)
+        self.idx = np.stack([idx for _, idx in self.parts])    # [k, n/k]
+        self.mesh = mesh
+        self.stacked = NamedSharding(mesh, _STACKED)
+        self.shared = NamedSharding(mesh, _SHARED)
+
+    def valid(self, valid_count: int) -> np.ndarray:
+        """Each part's share of ``valid_count`` dealt decisions."""
+        k = self.count
+        return (valid_count - np.arange(k) + k - 1) // k
+
+    def deal(self, rows: ArrivalRows, valid_count: int,
+             first: int) -> np.ndarray:
+        """The block's first ``valid_count`` rows dealt round-robin into one
+        ``[count, block, W]`` buffer, part ``c`` taking rows ``c, c + count,
+        …`` with local decision ids from ``first``.  A part with fewer
+        valid rows than ``block`` repeats its last (the offline driver's
+        edge padding); one with none repeats the block's last row, all
+        invalid."""
+        k, lane = self.count, np.arange(self.block)
+        c = np.arange(k)[:, None]
+        own = self.valid(valid_count)[:, None]
+        src = np.where(own > 0, c + k * np.minimum(lane, own - 1),
+                       valid_count - 1)
+        ids = np.broadcast_to(first + lane, src.shape).astype(np.int32)
+        return _pack(ids, ArrivalRows(*(p[src] for p in rows)), lane < own)
+
+    def merge(self, got) -> list:
+        """Fetched outputs in submission order and global server ids: the
+        seven ``[count, block]`` planes interleaved back by decision, then
+        any ``[count, n/count, …]`` views scattered to their servers."""
+        j = np.take_along_axis(self.idx, got[0], axis=1)
+
+        def by_task(a):
+            return np.swapaxes(a, 0, 1).reshape((-1,) + a.shape[2:])
+
+        def by_server(a):
+            out = np.empty((self.idx.size,) + a.shape[2:], a.dtype)
+            out[self.idx] = a
+            return out
+
+        return ([by_task(j)] + [by_task(a) for a in got[1:7]]
+                + [by_server(a) for a in got[7:]])
+
+
 class DecisionService:
     """Online scheduling over the offline engine's exact arithmetic.
 
@@ -115,14 +275,33 @@ class DecisionService:
     ``cache_faults``, ``use_kernel``.  ``cfg.retry``, ``cfg.trace``,
     ``cfg.locality`` and DAG workloads run host-side wave loops around
     the scan and are not streamable — they raise ``NotImplementedError``.
+
+    ``mini_clusters=k`` serves the fleet as the §4.2 mini-clusters (see the
+    module docstring): ``k`` must divide the fleet size and ``cfg.b``,
+    which stays the block the service steps, ``b/k`` decisions of each
+    part at a time; results are bit-exact with ``simulate_hierarchical(wl,
+    cluster, cfg._replace(b=b // k), k, seed, mode="batched", b=b // k)``.
+    It takes no ``dynamics`` and no checkpoints (``NotImplementedError``).
+    ``part_decisions`` counts each part's valid decisions.
     """
 
     def __init__(self, cluster: ClusterSpec, cfg: EngineConfig, *,
                  seed: int = 0, dynamics=None,
                  use_kernel: bool | str = "auto",
                  capacity: int = 1 << 16,
-                 publish_snapshots: bool = True):
+                 publish_snapshots: bool = True,
+                 mini_clusters: int = 1):
         _validate_config(cfg)
+        k = int(mini_clusters)
+        if k < 1 or cluster.num_servers % k or cfg.b % k:
+            raise ValueError(
+                f"mini_clusters={mini_clusters} must be ≥ 1 and divide the "
+                f"fleet's {cluster.num_servers} servers and b={cfg.b}")
+        if k > 1 and dynamics is not None:
+            raise NotImplementedError(
+                "DecisionService with mini_clusters and dynamics: a "
+                "timeline would have to be split over the parts — run it "
+                "offline via simulate_hierarchical().")
         if cfg.retry is not None:
             raise NotImplementedError(
                 "DecisionService with a RetryPolicy: the re-entry queue "
@@ -161,14 +340,21 @@ class DecisionService:
         self._use_kernel = use_kernel
         self._masked = masked
         self._faulted = faulted
-        self._scfg = _static_cfg(cfg, for_kernel=use_kernel, keep_b=True)
-        self._C, self._node_type, self._cores_per, self._mem_unit = \
-            _cluster_arrays(cluster, cfg.mem_units)
-        self._dyn = _make_dyn(cfg)
-        self._dyn_ints = _make_dyn_ints(cfg)
-        self._win = _lower_dynamics(dynamics, n)
-        self._base_key = jax.random.PRNGKey(self._seed)
-        self._carry = _init_carry(self._scfg, n, self._cores_per, faulted)
+        self._parts = None
+        if k == 1:
+            self._scfg = _static_cfg(cfg, for_kernel=use_kernel,
+                                     keep_b=True)
+            self._C, self._node_type, self._cores_per, self._mem_unit = \
+                _cluster_arrays(cluster, cfg.mem_units)
+            self._dyn = _make_dyn(cfg)
+            self._dyn_ints = _make_dyn_ints(cfg)
+            self._win = _lower_dynamics(dynamics, n)
+            self._base_key = jax.random.PRNGKey(self._seed)
+            self._carry = _init_carry(self._scfg, n, self._cores_per,
+                                      faulted)
+        else:
+            self._init_parts(cluster, cfg, k)
+        self._part_decisions = np.zeros(k, np.int64)
 
         self._ring = ArrivalRing(capacity, cluster.num_types)
         self._next_idx = 0
@@ -181,6 +367,36 @@ class DecisionService:
         self._publish = publish_snapshots
         self._snaps: list[dict | None] = [None, None]
         self._live = -1           # index of the published snapshot buffer
+
+    def _init_parts(self, cluster: ClusterSpec, cfg: EngineConfig,
+                    k: int) -> None:
+        """Each part's operands, stacked and placed one part (or an equal
+        share of the parts) a device; per-part ``n`` and ``b``."""
+        part_cfg = cfg._replace(b=cfg.b // k)
+        _validate_config(part_cfg)
+        parts = self._parts = _MiniClusters(cluster, k, cfg.b,
+                                            _parts_mesh(k))
+        self._n = cluster.num_servers // k
+        self._scfg = _static_cfg(part_cfg, for_kernel=self._use_kernel,
+                                 keep_b=True)
+        arrays = [_cluster_arrays(spec, cfg.mem_units)
+                  for spec, _ in parts.parts]
+        self._C, self._node_type, self._cores_per, self._mem_unit = (
+            jax.device_put(np.stack([np.asarray(a[i]) for a in arrays]),
+                           parts.stacked) for i in range(4))
+        self._dyn, self._dyn_ints, self._win = jax.device_put(
+            (_make_dyn(cfg), _make_dyn_ints(part_cfg),
+             _lower_dynamics(None, self._n)), parts.shared)
+        self._base_key = jax.device_put(
+            np.stack([np.asarray(jax.random.PRNGKey(self._seed + c))
+                      for c in range(k)]), parts.stacked)
+        self._carry = _init_carry_parts(self._cores_per, self._scfg,
+                                        self._n, parts.mesh)
+        if parts.mesh.size == 1:
+            # One device returns every leaf of the step's carry unsharded;
+            # laid out so from the start, the first step compiles the one
+            # program every later step runs.
+            self._carry = jax.device_put(self._carry, parts.shared)
 
     # -- ingestion --------------------------------------------------------
 
@@ -196,9 +412,16 @@ class DecisionService:
 
     @property
     def compiles(self) -> int:
-        """Compiled-program count of the shared step — steady-state
-        steps must not grow this (asserted in tests)."""
-        return _serve_step._cache_size()
+        """Compiled-program count of the shared steps, one-part and
+        mini-cluster — steady-state steps must not grow this (asserted in
+        tests)."""
+        return _serve_step._cache_size() + _serve_step_parts._cache_size()
+
+    @property
+    def part_decisions(self) -> np.ndarray:
+        """Valid decisions made so far in each mini-cluster (``[k]``; one
+        entry without mini-clusters); sums to :attr:`scheduled`."""
+        return self._part_decisions.copy()
 
     def submit(self, r_submit, r_exec, d_est, d_act, submit_ms) -> int:
         """Enqueue an arrival chunk (numpy planes, any length ≥ 0).
@@ -262,9 +485,10 @@ class DecisionService:
         with TraceAnnotation("serve.flush", block=block):
             with TraceAnnotation("serve.ring_pop", block=block):
                 rows = self._ring.pop(k)
-                padded = ArrivalRows(*(edge(np.asarray(p)) for p in rows))
+                if self._parts is None:    # mini-clusters pad each part
+                    rows = ArrivalRows(*(edge(np.asarray(p)) for p in rows))
             self._ring_pad += pad
-            return done + self._run_block(padded, k, block)
+            return done + self._run_block(rows, k, block)
 
     def _stage(self, rows: ArrivalRows, valid_count: int) -> np.ndarray:
         """Block ``rows`` as the one int32 buffer it is uploaded in, a row
@@ -273,17 +497,16 @@ class DecisionService:
         rest edge-pad a ragged tail)."""
         b = self._b
         ids = np.arange(self._next_idx, self._next_idx + b, dtype=np.int32)
-        valid = (np.arange(b) < valid_count).astype(np.int32)
-        cols = (ids[:, None], rows.r_submit, rows.r_exec.reshape(b, -1),
-                rows.d_est, rows.d_act, rows.submit_ms[:, None],
-                valid[:, None])
-        return np.concatenate([c.view(np.int32) for c in cols], axis=1)
+        return _pack(ids, rows, np.arange(b) < valid_count)
 
     def _upload(self, buf: np.ndarray) -> tuple:
         """Send a staged block in one transfer and split it on the device
-        into the step's block operand."""
-        return _unpack_block(buf, tt=self.cluster.num_types,
-                             k=self._C.shape[1])
+        (each part's on its own) into the step's block operand."""
+        tt, k = self.cluster.num_types, self._C.shape[-1]
+        if self._parts is None:
+            return _unpack_block(buf, tt=tt, k=k)
+        return _unpack_parts(jax.device_put(buf, self._parts.stacked),
+                             tt=tt, k=k, mesh=self._parts.mesh)
 
     def _step_operands(self, blk) -> tuple:
         return (self._carry, blk, self._C, self._node_type, self._mem_unit,
@@ -291,22 +514,47 @@ class DecisionService:
                 self._base_key)
 
     def _step_statics(self) -> dict:
-        return dict(cfg=self._scfg, n=self._n, use_kernel=self._use_kernel,
-                    kernel_masked=self._masked, cache_faulted=self._faulted)
+        statics = dict(cfg=self._scfg, n=self._n,
+                       use_kernel=self._use_kernel,
+                       kernel_masked=self._masked,
+                       cache_faulted=self._faulted)
+        if self._parts is not None:
+            statics["mesh"] = self._parts.mesh
+        return statics
+
+    def _staged(self, rows: ArrivalRows, valid_count: int,
+                block: int) -> np.ndarray:
+        """The block's upload buffer: staged, or dealt to the parts."""
+        parts = self._parts
+        if parts is None:
+            return self._stage(rows, valid_count)
+        with TraceAnnotation("serve.deal", block=block, parts=parts.count):
+            return parts.deal(rows, valid_count,
+                              self._next_idx // parts.count)
 
     def lower_step(self, sharding=None):
         """Lower the step program for this service's block shapes, to
         inspect what the compiler emits (``.compile().as_text()``).  With
         a ``sharding`` the operands are abstract shapes placed there — a
         device of a described topology compiles for a chip that is not
-        attached."""
+        attached.  With mini-clusters ``sharding`` is a one-axis
+        :class:`~jax.sharding.Mesh` (say, of a described topology's
+        devices) over which the parts are laid instead of the service's
+        own."""
         operands = self._step_operands(self._upload(
-            self._stage(self._ring.zeros(self._b), self._b)))
+            self._staged(self._ring.zeros(self._b), self._b, 0)))
+        statics = self._step_statics()
+        step = _serve_step if self._parts is None else _serve_step_parts
+        places = [sharding] * len(operands)
+        if self._parts is not None and sharding is not None:
+            statics["mesh"] = sharding
+            places = [NamedSharding(sharding, s) for s in _STEP_SPECS]
         if sharding is not None:
-            operands = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                               sharding=sharding), operands)
-        return _serve_step.lower(*operands, **self._step_statics())
+            operands = [jax.tree.map(
+                lambda x, p=p: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                                    sharding=p), op)
+                for op, p in zip(operands, places)]
+        return step.lower(*operands, **statics)
 
     def _run_block(self, rows: ArrivalRows, valid_count: int, k: int) -> int:
         """Block ``k``'s round trip, one ``serve.*`` span per phase: one
@@ -314,15 +562,18 @@ class DecisionService:
         output planes and the published views, whose copies are queued
         behind the step as soon as it is dispatched."""
         b = self._b
+        parts = self._parts
+        tags = {} if parts is None else {"parts": parts.count}
         t0 = time.perf_counter()
-        with TraceAnnotation("serve.upload", block=k) as span:
-            buf = self._stage(rows, valid_count)
+        with TraceAnnotation("serve.upload", block=k, **tags) as span:
+            buf = self._staged(rows, valid_count, k)
             span.set_metadata(arrays=1, bytes=buf.nbytes)
             blk = self._upload(buf)
         t_dispatch = time.perf_counter()
+        step = _serve_step if parts is None else _serve_step_parts
         with TraceAnnotation("serve.dispatch", block=k):
-            self._carry, out = _serve_step(*self._step_operands(blk),
-                                           **self._step_statics())
+            self._carry, out = step(*self._step_operands(blk),
+                                    **self._step_statics())
             fetch = tuple(out[:7])
             if self._publish:
                 fetch += (self._carry.view_L, self._carry.view_D,
@@ -337,11 +588,16 @@ class DecisionService:
         self.ring_wait.record((t_dispatch - t_enq) * 1e3)
         self.decision_latency.record((t1 - t_enq) * 1e3)
         with TraceAnnotation("serve.readback", block=k, arrays=len(fetch),
-                             bytes=sum(a.nbytes for a in fetch)):
+                             bytes=sum(a.nbytes for a in fetch), **tags):
             got = jax.device_get(fetch)
+            if parts is not None:
+                with TraceAnnotation("serve.merge", block=k, **tags):
+                    got = parts.merge(got)
             for acc, plane in zip(self._outs[:7], got):
                 acc.append(plane[:valid_count])
         self._outs[7].append(rows.submit_ms[:valid_count])
+        self._part_decisions += (valid_count if parts is None
+                                 else parts.valid(valid_count))
         self._next_idx += b
         self._steps += 1
         if self._publish:
@@ -375,7 +631,7 @@ class DecisionService:
             raise ValueError("no decisions yet")
         j, start, finish, enq, sched_ms, cores, mem_mb, submit = (
             np.concatenate(acc) for acc in self._outs)
-        msgs = np.asarray(self._carry.msgs)
+        msgs = np.asarray(self._carry.msgs).reshape(-1, 4).sum(axis=0)
         return SimResult(
             server=j.astype(np.int32), submit_ms=submit,
             enqueue_ms=enq, start_ms=start, finish_ms=finish,
@@ -403,6 +659,10 @@ class DecisionService:
         are not part of cluster state); resuming a fresh service from the
         returned dict and replaying the remaining stream is bit-exact
         with never having stopped."""
+        if self._parts is not None:
+            raise NotImplementedError(
+                "export_checkpoint with mini_clusters: a checkpoint holds "
+                "one part's carry")
         if self._ring.count:
             raise ValueError(
                 f"{self._ring.count} buffered arrivals — drain()/flush() "
@@ -421,6 +681,10 @@ class DecisionService:
         ``cluster``/``cfg``/``seed``/``dynamics`` must match the
         exporting service (the checkpoint pins the identity-shaping
         ones)."""
+        if kwargs.get("mini_clusters", 1) != 1:
+            raise NotImplementedError(
+                "from_checkpoint with mini_clusters: a checkpoint holds "
+                "one part's carry")
         svc = cls(cluster, cfg, seed=ckpt["seed"], **kwargs)
         for key, have in (("policy", cfg.policy), ("b", cfg.b),
                           ("faulted", svc._faulted)):

@@ -6,8 +6,9 @@ would raise on the chip (unsupported primitives, illegal block shapes,
 scoped-VMEM overflow).  The sparse megakernel is compiled alone, unmasked
 and masked, at the decision-block and fleet widths users run, and inside
 the jitted ``_serve_step`` for ``dodoor`` at the testbed width and at
-n = 10⁴.  Each compiled program must contain the Mosaic kernel
-(``tpu_custom_call``).
+n = 10⁴, and the four-mini-cluster step of the 10⁵-server cell over the
+2x2 host's four chips.  Each compiled program must contain the Mosaic
+kernel (``tpu_custom_call``).
 
 The topology is described inside a module-scoped fixture, so that only the
 test process that runs this file loads the TPU library.  The persistent
@@ -20,7 +21,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+import numpy as np
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels.dodoor_choice import dodoor_fused_sparse
 from repro.serve import DecisionService
@@ -28,7 +30,7 @@ from repro.sim import EngineConfig, make_scaled, make_testbed
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -39,9 +41,19 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("parts",))
 
 
 @pytest.mark.parametrize("masked", (False, True))
@@ -96,3 +108,31 @@ def test_serve_step_stages_are_named(one_chip):
     assert kernel.lstrip().startswith("%dodoor_fused_sparse.")
     assert "/select/" in op_name(kernel)
     assert op_name(kernel).endswith("/dodoor_fused_sparse/pallas_call")
+
+
+def test_mini_cluster_step_compiles_on_four_chips(four_chips):
+    """The step of ``cell100k-azure``: 10⁵ servers as four mini-clusters,
+    b = 50,000, one part a chip of the 2x2 host.  Each chip runs the
+    one-part program (25,000 servers, 12,500 decisions) with the kernel
+    compiled, exchanges nothing with the others, and holds one part's
+    carry."""
+    cluster = make_scaled(100_000)
+    cfg = EngineConfig(policy="dodoor", b=50_000, interpret=False)
+    svc = DecisionService(cluster, cfg, use_kernel=True, capacity=50_000,
+                          mini_clusters=4)
+    compiled = svc.lower_step(four_chips).compile()
+    text = compiled.as_text()
+    kernel, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernel.lstrip().startswith("%dodoor_fused_sparse.")
+    assert "f32[25000,256]" in text and "f32[100000" not in text
+    # The stage scopes name each chip's instructions as on one chip, so a
+    # trace's ops map to stages.
+    whiles = [re.search(r'op_name="([^"]*)"', ln).group(1)
+              for ln in text.splitlines() if " while(" in ln]
+    assert whiles and all("/commit/" in n for n in whiles)
+    assert "/select/" in re.search(r'op_name="([^"]*)"', kernel).group(1)
+    assert not re.search(r"all-reduce|all-gather|all-to-all|"
+                         r"collective-permute", text)
+    carry = sum(np.prod(x.shape) * x.dtype.itemsize
+                for x in jax.tree.leaves(svc._carry)) // 4
+    assert compiled.memory_analysis().argument_size_in_bytes < 1.1 * carry

@@ -288,6 +288,14 @@ class TestTracing:
         assert (wait <= svc.decision_latency.samples()).all()
         assert svc.latency_summary()["ring_wait"]["count"] == m
 
+    def test_one_part_adds_no_spans_or_tags(self, traced_stats):
+        """Without mini-clusters the trace is as it was: no ``serve.deal``
+        or ``serve.merge``, and no ``parts`` tag."""
+        _, events = traced_stats
+        names = {name for name, _, _, _ in events}
+        assert not names & {"serve.deal", "serve.merge"}
+        assert all("parts" not in tags for _, tags, _, _ in events)
+
 
 class TestDonationAndCompiles:
     def test_zero_recompiles_after_warmup(self, cluster, wl):
@@ -430,3 +438,197 @@ class TestRingAndLatencyUnits:
         rec = LatencyRecorder()
         rec.record([0.0, 2.0, 3.0])        # below the 1 ns floor
         assert sum(rec.histogram(nbins=4)["counts"]) == 3
+
+
+def _hierarchical(wl, cluster, cfg, k, seed):
+    """The offline oracle of a ``mini_clusters=k`` service."""
+    from repro.sim import simulate_hierarchical
+    b = cfg.b // k
+    return simulate_hierarchical(wl, cluster, cfg._replace(b=b), k, seed,
+                                 mode="batched", b=b)
+
+
+def _serve_parts(wl, cluster, cfg, k, seed, chunk):
+    m = wl.r_submit.shape[0]
+    svc = DecisionService(cluster, cfg, seed=seed, capacity=m,
+                          mini_clusters=k)
+    for lo in range(0, m, chunk):
+        svc.submit_workload(wl, lo, min(lo + chunk, m))
+        svc.drain()
+    svc.flush()
+    return svc, svc.result()
+
+
+class TestMiniClusters:
+    """``mini_clusters=k``: the §4.2 split on the served path, bit-exact
+    with ``simulate_hierarchical`` (here every part rides one device's
+    ``vmap``; the four-device mesh runs in a subprocess below)."""
+
+    @pytest.fixture(scope="class")
+    def vms(self):
+        from repro.workloads import azure
+        return azure.synthesize(m=317, qps=5.0, seed=1)
+
+    @pytest.mark.parametrize("chunk", (7, 40))
+    @pytest.mark.parametrize("mix", ("functionbench", "azure"))
+    @pytest.mark.parametrize("k", (2, 4))
+    @pytest.mark.parametrize("policy", ("dodoor", "pot"))
+    def test_bit_exact_with_simulate_hierarchical(self, cluster, wl, vms,
+                                                  policy, k, mix, chunk):
+        tasks = wl if mix == "functionbench" else vms
+        cfg = EngineConfig(policy=policy, b=20)      # 317 = 15·20 + 17
+        off = _hierarchical(tasks, cluster, cfg, k, seed=3)
+        svc, res = _serve_parts(tasks, cluster, cfg, k, 3, chunk)
+        _assert_same(off, res, (policy, k, mix, chunk))
+        parts = svc.part_decisions
+        assert parts.shape == (k,) and parts.sum() == svc.scheduled == 317
+        assert parts.tolist() == [len(range(c, 317, k)) for c in range(k)]
+
+    @pytest.mark.parametrize("policy", ("dodoor", "pot"))
+    def test_tail_that_leaves_parts_empty(self, cluster, wl, policy):
+        """A one-task tail: three of four parts step a block with no valid
+        row, which must leave their state as it was."""
+        from dataclasses import replace
+        short = replace(wl, **{f: getattr(wl, f)[:301] for f in (
+            "r_submit", "r_exec", "d_est", "d_act", "task_type",
+            "submit_ms")})
+        cfg = EngineConfig(policy=policy, b=20)
+        off = _hierarchical(short, cluster, cfg, 4, seed=0)
+        svc, res = _serve_parts(short, cluster, cfg, 4, 0, 50)
+        _assert_same(off, res, policy)
+        assert svc.part_decisions.tolist() == [76, 75, 75, 75]
+
+    def test_one_compile_and_snapshots_over_the_whole_fleet(self, cluster,
+                                                            wl):
+        cfg = EngineConfig(policy="dodoor", b=20)
+        svc = DecisionService(cluster, cfg, seed=0, capacity=317,
+                              mini_clusters=4)
+        svc.submit_workload(wl)
+        svc.step()
+        warm = svc.compiles
+        svc.drain()
+        svc.flush()
+        assert svc.compiles == warm
+        snap = svc.snapshot()
+        n = cluster.num_servers
+        assert snap["view_L"].shape == (n, 2)
+        assert snap["view_D"].shape == snap["view_rif"].shape == (n,)
+        # Each server's view is its own part's: server j of part j mod 4.
+        carry_D = np.asarray(svc._carry.view_D)               # [4, n/4]
+        for c in range(4):
+            assert np.array_equal(snap["view_D"][c::4], carry_D[c])
+
+    def test_one_part_is_the_served_path(self, cluster, wl):
+        """``mini_clusters=1`` is today's service: the same placements and
+        the same step program."""
+        cfg = EngineConfig(policy="dodoor", b=25)
+        plain = DecisionService(cluster, cfg, seed=0, capacity=317)
+        one = DecisionService(cluster, cfg, seed=0, capacity=317,
+                              mini_clusters=1)
+        assert one.lower_step().as_text() == plain.lower_step().as_text()
+        for svc in (plain, one):
+            svc.submit_workload(wl)
+            svc.flush()
+        _assert_same(plain.result(), one.result(), "k=1")
+        assert one.part_decisions.tolist() == [317]
+
+    @pytest.mark.parametrize("k,b", [(3, 21), (4, 30), (0, 20), (40, 40)])
+    def test_parts_must_divide_fleet_and_block(self, cluster, k, b):
+        with pytest.raises(ValueError, match="mini_clusters"):
+            DecisionService(cluster, EngineConfig(policy="dodoor", b=b),
+                            mini_clusters=k)
+
+    def test_no_dynamics_or_checkpoints(self, cluster, wl):
+        cfg = EngineConfig(policy="dodoor", b=20)
+        with pytest.raises(NotImplementedError, match="dynamics"):
+            DecisionService(cluster, cfg, mini_clusters=2,
+                            dynamics=Dynamics(outages=((3, 1.0, 9.0),)))
+        svc = DecisionService(cluster, cfg, mini_clusters=2)
+        with pytest.raises(NotImplementedError, match="checkpoint"):
+            svc.export_checkpoint()
+        plain = DecisionService(cluster, cfg)
+        plain.submit_workload(wl, 0, 40)
+        plain.drain()
+        with pytest.raises(NotImplementedError, match="checkpoint"):
+            DecisionService.from_checkpoint(
+                cluster, cfg, plain.export_checkpoint(), mini_clusters=2)
+
+    def test_deal_and_merge_spans(self, cluster, wl, tmp_path):
+        """``serve.deal`` inside ``serve.upload`` and ``serve.merge`` inside
+        ``serve.readback``, once a block, all four tagged with the parts."""
+        import glob
+        import os
+
+        import jax
+        from jax.profiler import ProfileData
+        svc = DecisionService(cluster, EngineConfig(policy="dodoor", b=20),
+                              capacity=317, mini_clusters=4)
+        svc.submit_workload(wl)
+        svc.step()
+        with jax.profiler.trace(str(tmp_path)):
+            svc.flush()
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        events = {e.name: (dict(e.stats), e.start_ns, e.end_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("serve.")}
+        for inner, outer in (("serve.deal", "serve.upload"),
+                             ("serve.merge", "serve.readback")):
+            (tags, lo, hi), (otags, olo, ohi) = events[inner], events[outer]
+            assert olo <= lo and hi <= ohi
+            assert tags["parts"] == otags["parts"] == 4
+            assert tags["block"] == otags["block"] == 15
+
+    def test_four_devices_one_part_each(self):
+        """On four (virtual) devices every part's state lives on its own
+        device, the step is one program, and the results match the offline
+        oracle for k = 4 and k = 2."""
+        import os
+        import subprocess
+        import sys
+        code = r"""
+import jax, numpy as np
+from repro.serve import DecisionService
+from repro.sim import EngineConfig, make_testbed, simulate_hierarchical
+from repro.workloads import azure, functionbench as fb
+assert len(jax.devices()) == 4
+cluster = make_testbed(scale=0.4)
+for wl in (fb.synthesize(m=317, qps=60.0, seed=0),
+           azure.synthesize(m=301, qps=5.0, seed=1)):
+    for k in (4, 2):
+        cfg = EngineConfig(policy="dodoor", b=20)
+        m = wl.r_submit.shape[0]
+        off = simulate_hierarchical(wl, cluster, cfg._replace(b=20 // k), k,
+                                    7, mode="batched", b=20 // k)
+        svc = DecisionService(cluster, cfg, seed=7, capacity=m,
+                              mini_clusters=k)
+        held = svc._carry.rb_release.sharding.device_set
+        assert len(held) == k, held
+        svc.submit_workload(wl)
+        svc.step()
+        warm = svc.compiles
+        svc.flush()
+        assert svc.compiles == warm
+        res = svc.result()
+        assert (off.server == res.server).all()
+        for f in ("start_ms", "finish_ms", "enqueue_ms", "sched_ms"):
+            assert np.array_equal(getattr(off, f), getattr(res, f)), f
+        assert off.msgs_total == res.msgs_total
+        assert (off.msgs_push, off.msgs_flush) == (res.msgs_push,
+                                                   res.msgs_flush)
+        assert svc.part_decisions.sum() == m
+print("mini-clusters on four devices exact")
+"""
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {**os.environ,
+               "PYTHONPATH": os.path.join(repo, "src"),
+               "JAX_PLATFORMS": "cpu",
+               "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
+                             " --xla_force_host_platform_device_count=4")
+               .strip()}
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=420)
+        assert out.returncode == 0, out.stdout + out.stderr
+        assert "mini-clusters on four devices exact" in out.stdout
