@@ -963,18 +963,30 @@ def _simulate_jax(xs, C, node_type, mem_unit, cores_per, dyn_vec, dyn_ints,
 
 def _sorted_fill(arr, k, value):
     """Replace the ``k`` smallest entries of each sorted-ascending row of
-    ``arr`` [n, W] with ``value`` [n] (``value`` ≥ the k-th smallest entry),
-    keeping the row sorted — an O(W) shift-merge: drop the first ``k``
-    entries, then splice the ``k`` copies of ``value`` at their rank."""
-    n, W = arr.shape
+    ``arr`` [n, W] with ``value`` [n] (``value`` ≥ the k-th smallest entry,
+    ``1 ≤ k ≤ W``), keeping the row sorted — an O(W) shift-merge: drop the
+    first ``k`` entries, then splice the ``k`` copies of ``value`` at their
+    rank.
+
+    The shift is gather-free: each row moves left by ``k`` in ⌈log₂W⌉
+    static steps, one per bit of ``k`` (a static slice and concatenate,
+    kept where that bit is set).  Lanes wrapped round from the front are
+    never read, since only positions below ``idx ≤ W - k`` take the
+    shifted row."""
+    W = arr.shape[1]
     iota = jnp.arange(W, dtype=jnp.int32)[None, :]
     kk = k[:, None]
     # Rank of `value` among the surviving entries arr[k:].
     idx = jnp.sum((iota >= kk) & (arr < value[:, None]), axis=1)[:, None]
-    src = jnp.where(iota < idx, iota + kk, iota)
-    gathered = jnp.take_along_axis(arr, jnp.minimum(src, W - 1), axis=1)
+    shifted = arr
+    bit = 1
+    while bit < W:
+        rolled = jnp.concatenate([shifted[:, bit:], shifted[:, :bit]], axis=1)
+        shifted = jnp.where((kk & bit) != 0, rolled, shifted)
+        bit *= 2
     in_win = (iota >= idx) & (iota < idx + kk)
-    return jnp.where(in_win, value[:, None], gathered)
+    return jnp.where(iota < idx, shifted,
+                     jnp.where(in_win, value[:, None], arr))
 
 
 def _commit_rounds(carry: _Carry, valid, now, j, cores, mem_mb, dur_raw,
@@ -996,7 +1008,9 @@ def _commit_rounds(carry: _Carry, valid, now, j, cores, mem_mb, dur_raw,
     The commit reads core/mem unit state only as a *multiset* (c-th earliest
     free time, count busy past ``start``) and replaces the ``c_eff`` earliest
     units with ``finish``; this driver keeps each row sorted ascending and
-    performs that update as an O(width) shift-merge — no sorts in the loop —
+    performs that update as an O(width) shift-merge (:func:`_sorted_fill`)
+    — no sorts in the loop, and no gather over whole rows: the shift is
+    static slices and selects —
     which yields bit-identical results to :func:`_commit_one`'s rank-based
     form (the oracle's per-unit identities never reach any output).
 

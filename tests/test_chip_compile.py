@@ -8,7 +8,8 @@ and masked, at the decision-block and fleet widths users run, and inside
 the jitted ``_serve_step`` for ``dodoor`` at the testbed width and at
 n = 10⁴, and the four-mini-cluster step of the 10⁵-server cell over the
 2x2 host's four chips.  Each compiled program must contain the Mosaic
-kernel (``tpu_custom_call``).
+kernel (``tpu_custom_call``), and its commit rounds must shift the sorted
+core/memory rows without a gather over whole rows.
 
 The topology is described inside a module-scoped fixture, so that only the
 test process that runs this file loads the TPU library.  The persistent
@@ -27,6 +28,25 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from repro.kernels.dodoor_choice import dodoor_fused_sparse
 from repro.serve import DecisionService
 from repro.sim import EngineConfig, make_scaled, make_testbed
+from repro.sim.cluster import CMAX
+
+
+def assert_no_row_gather(text, n, mem_units):
+    """No ``take_along_axis`` gather inside the commit rounds returns a
+    whole [n, CMAX] core or [n, mem_units] memory plane: ``_sorted_fill``
+    shifts those rows with static slices and selects."""
+    rows = {n * CMAX, n * mem_units}
+    seen = 0
+    for ln in text.splitlines():
+        if " gather(" not in ln or ("/commit/while/body/jit(take_along_axis)"
+                                    not in ln):
+            continue
+        seen += 1
+        dims = re.search(r"= \w+\[([\d,]*)\]", ln).group(1)
+        size = int(np.prod([int(d) for d in dims.split(",") if d]))
+        assert size not in rows, ln.strip()[:160]
+    # The c-th-earliest row picks (one element a server) are still there.
+    assert seen
 
 
 @pytest.fixture(scope="module")
@@ -86,8 +106,9 @@ def test_serve_step_compiles_with_kernel(one_chip, n, b, masked):
     assert cluster.num_servers == n
     cfg = EngineConfig(policy="dodoor", b=b, interpret=False)
     svc = DecisionService(cluster, cfg, use_kernel=True, capacity=b)
-    compiled = svc.lower_step(one_chip).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = svc.lower_step(one_chip).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert_no_row_gather(text, n, cfg.mem_units)
 
 
 def test_serve_step_stages_are_named(one_chip):
@@ -125,6 +146,7 @@ def test_mini_cluster_step_compiles_on_four_chips(four_chips):
     kernel, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert kernel.lstrip().startswith("%dodoor_fused_sparse.")
     assert "f32[25000,256]" in text and "f32[100000" not in text
+    assert_no_row_gather(text, 25_000, cfg.mem_units)
     # The stage scopes name each chip's instructions as on one chip, so a
     # trace's ops map to stages.
     whiles = [re.search(r'op_name="([^"]*)"', ln).group(1)

@@ -276,6 +276,62 @@ class TestKernelEnginePath:
         assert a.msgs_total == bvt.msgs_total
 
 
+def _sorted_fill_gather(arr, k, value):
+    """The shift-merge in its plain gather form: each kept lane reads the
+    entry ``k`` places to its right through ``take_along_axis``."""
+    import jax.numpy as jnp
+    W = arr.shape[1]
+    iota = jnp.arange(W, dtype=jnp.int32)[None, :]
+    kk = k[:, None]
+    idx = jnp.sum((iota >= kk) & (arr < value[:, None]), axis=1)[:, None]
+    src = jnp.where(iota < idx, iota + kk, iota)
+    gathered = jnp.take_along_axis(arr, jnp.minimum(src, W - 1), axis=1)
+    in_win = (iota >= idx) & (iota < idx + kk)
+    return jnp.where(in_win, value[:, None], gathered)
+
+
+class TestSortedFill:
+    """``_sorted_fill``'s gather-free shift equals the gather form bit for
+    bit, for every ``k`` in 1..W, and leaves each row sorted."""
+
+    @pytest.mark.parametrize("kind", ("ties", "kth", "inf_pad"))
+    @pytest.mark.parametrize("W", (1, 7, 28, 64))
+    def test_matches_gather_form(self, W, kind):
+        import jax.numpy as jnp
+        from repro.sim.engine import _sorted_fill
+        rng = np.random.RandomState(W)
+        reps = 16
+        k = np.repeat(np.arange(1, W + 1, dtype=np.int32), reps)   # [n]
+        n = k.size
+        # Few distinct values, so rows and values tie often.
+        arr = np.sort(rng.randint(0, 6, (n, W)).astype(np.float32), axis=1)
+        if kind == "inf_pad":
+            # Like _init_carry's core_free: units past a server's count
+            # are +inf, and a task takes at most that many units.
+            width = rng.randint(k, W + 1)
+            arr[np.arange(W)[None, :] >= width[:, None]] = np.inf
+        kth = arr[np.arange(n), k - 1]
+        if kind == "kth":
+            value = kth
+        else:
+            # An entry of the row at or past the k-th (finite under
+            # padding), or a fresh value above it.
+            top = np.sum(np.isfinite(arr), axis=1) - 1
+            pick = arr[np.arange(n), rng.randint(k - 1, top + 1)]
+            value = np.where(rng.rand(n) < 0.7, pick,
+                             kth + rng.randint(0, 4, n))
+        value = value.astype(np.float32)
+        assert (value >= kth).all() and np.isfinite(value).all()
+        args = (jnp.asarray(arr), jnp.asarray(k), jnp.asarray(value))
+        got = np.asarray(_sorted_fill(*args))
+        want = np.asarray(_sorted_fill_gather(*args))
+        assert np.array_equal(got.view(np.int32), want.view(np.int32))
+        assert (got[:, 1:] >= got[:, :-1]).all()
+        # The k smallest entries gave way to k copies of the value.
+        for row, kr, v, a in zip(got, k, value, arr):
+            assert np.array_equal(row, np.sort(np.r_[a[kr:], [v] * kr]))
+
+
 class TestFusedMegakernelDraws:
     """The megakernel's in-kernel sampling pinned draw-for-draw to the
     two-stage ``sample_feasible_batch`` + ``dodoor_choice_ref`` path at
